@@ -667,6 +667,9 @@ def main(argv=None) -> None:
     ap.add_argument("--check-threshold", type=float, default=0.25,
                     help="allowed relative regression for --check")
     args = ap.parse_args(argv)
+    from repro.launch.cli import enable_compile_cache
+
+    enable_compile_cache()
     if args.check:
         print("name,us_per_call,derived")
         failures = run_check(threshold=args.check_threshold)

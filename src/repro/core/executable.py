@@ -39,6 +39,8 @@ from . import placement as placement_mod
 from . import partition as partition_mod
 from . import scheduler as scheduler_mod
 from ..analysis import verifier as verifier_mod
+from ..obs import metrics as metrics_mod
+from ..runtime.devices import local_kind
 from ..runtime.rendezvous import Rendezvous
 
 # Ops whose side effects cannot be replayed for a reference re-execution:
@@ -322,6 +324,7 @@ class Executable:
             if self.fuse_regions:
                 fus = fusion_mod.try_fuse(
                     session.graph, self.node_set, placement=None,
+                    device_kind=local_kind(),
                     feeds=self.feed_keys, fetch_refs=self.fetches,
                     written_vars=fusion_mod.written_variables(
                         session.graph, self.node_set),
@@ -378,11 +381,11 @@ class Executable:
                                                  self.node_set)
                     & {n for n in self.node_set
                        if session.graph.nodes[n].op == "Variable"})
-                kinds = ("cpu",)
+                kinds = (local_kind(),)
                 if self.multi_device and getattr(self, "placement", None):
                     kinds = tuple(sorted(
-                        {fusion_mod._device_kind(d, "cpu")
-                         for d in self.placement.values()})) or ("cpu",)
+                        {fusion_mod._device_kind(d, kinds[0])
+                         for d in self.placement.values()})) or kinds
                 self._guard_tol = numerics_mod.tolerance_for_ops(
                     ops, device_kinds=kinds, backend=self.kernel_backend)
                 self._guard_every = getattr(session, "parity_guard_every", None)
@@ -537,6 +540,7 @@ class Executable:
                     f"fetches {[str(r) for r in self.fetches]} "
                     f"(DESIGN.md §9)", RuntimeWarning, stacklevel=3)
                 self._strict_fallback = True
+                metrics_mod.counter("numerics.guard_demotions").inc()
                 for n, v in ref_vars.items():
                     store.write(n, v)
                 # cleared only with the verdict, inside the lock: an
@@ -612,6 +616,7 @@ class Executable:
                     f"fetches {[str(r) for r in self.fetches]} "
                     f"(DESIGN.md §9/§13)", RuntimeWarning, stacklevel=3)
                 self._strict_fallback = True
+                metrics_mod.counter("numerics.guard_demotions").inc()
                 plan.restore_variables(ref_vars)
                 self._parity_pending = False
                 return ref
@@ -672,8 +677,6 @@ class Executable:
                    executors: Optional[Dict[str, Executor]] = None,
                    fetch_by_dev: Optional[Dict[str, List[int]]] = None,
                    remap: bool = True, spans: Any = None) -> List[Any]:
-        from ..obs import metrics as metrics_mod
-
         session = self.session
         executors = executors if executors is not None else self.device_executors
         fetch_by_dev = (fetch_by_dev if fetch_by_dev is not None

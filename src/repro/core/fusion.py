@@ -189,19 +189,8 @@ class RegionSpec:
         """
         fn = self._lowered()
         if self.numerics == "strict":
-            try:
-                return jax.jit(fn, compiler_options={
-                    "xla_backend_optimization_level": 0})
-            except TypeError:  # older jax without compiler_options
-                import warnings
-
-                warnings.warn(
-                    "this jax version cannot compile fused regions "
-                    "at backend-opt-level 0; region "
-                    f"{self.name!r} falls back to numerics='fast' "
-                    "(fused results may differ from unfused by "
-                    "~1 ulp)", RuntimeWarning, stacklevel=2)
-                self.numerics = "fast"  # report the effective mode
+            return jax.jit(fn, compiler_options={
+                "xla_backend_optimization_level": 0})
         # "fast": plain jax.jit == full XLA backend optimization (FMA
         # contraction, reduction reassociation) — the §9 tolerance
         # contract bounds the drift and the CI parity gate enforces it

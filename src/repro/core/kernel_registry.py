@@ -39,9 +39,14 @@ class BackendError(ValueError):
 
 
 def _interpret() -> bool:
-    # interpret=True emulates the Pallas kernels through XLA on CPU/GPU
-    # pools; on a real TPU the same entry points compile to Mosaic.
-    return jax.default_backend() != "tpu"
+    # On a TPU the kernels compile to Mosaic; on the CPU (tests, host
+    # worker pools) they run in interpret mode.  No other backend can run
+    # them, and quietly interpreting there would hide that.
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise BackendError(f"the Pallas kernels run on tpu (compiled) or "
+                           f"cpu (interpreted), not on {backend!r}")
+    return backend == "cpu"
 
 
 def _feasible(*dims: int, block: int = 128) -> bool:

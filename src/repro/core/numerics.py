@@ -147,8 +147,12 @@ BACKEND_CALIBRATION: Dict[str, Dict[str, Tolerance]] = {
 
 def tolerance_table(device_kind: str = "cpu",
                     backend: str = "generic") -> Dict[str, Tolerance]:
-    """The effective per-class table for one (device kind, backend)."""
-    table = dict(TOLERANCES.get(device_kind, TOLERANCES["cpu"]))
+    """The effective per-class table for one (device kind, backend).
+    A device kind with no calibrated table is an error, not the CPU's."""
+    if device_kind not in TOLERANCES:
+        raise KeyError(f"no tolerance table for device kind {device_kind!r}; "
+                       f"calibrated kinds: {sorted(TOLERANCES)}")
+    table = dict(TOLERANCES[device_kind])
     for cls, tol in BACKEND_CALIBRATION.get(backend, {}).items():
         table[cls] = table.get(cls, tol) | tol
     return table

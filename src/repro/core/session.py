@@ -30,6 +30,7 @@ from .options import SessionOptions, parse_guard
 from . import ops as ops_mod
 from . import kernel_registry
 from ..runtime.containers import VariableStore, ContainerManager
+from ..runtime.devices import local_kind
 from ..runtime.rendezvous import Rendezvous
 
 
@@ -389,6 +390,7 @@ class Session:
             rendezvous=self.rendezvous,
             queues=self.queues,
             checkpoint_io=self.checkpoint_io,
+            device_kind=local_kind(),
         )
 
     # ------------------------------------------------------------------

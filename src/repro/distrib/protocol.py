@@ -37,6 +37,8 @@ import time
 from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as obs_metrics
@@ -84,14 +86,7 @@ def encode_tensor(x: Any) -> bytes:
     """
     if isinstance(x, _DeadTensor):
         return struct.pack(">B", _FLAG_DEAD)
-    flags = 0
-    try:
-        import jax
-
-        if isinstance(x, jax.Array):
-            flags |= _FLAG_JAX
-    except ImportError:  # pragma: no cover - jax is a hard dep elsewhere
-        pass
+    flags = _FLAG_JAX if isinstance(x, jax.Array) else 0
     arr = np.asarray(x)
     if not arr.flags.c_contiguous:
         # 0-d arrays are always contiguous, so this can never flatten a
@@ -122,8 +117,6 @@ def decode_tensor(data: bytes) -> Any:
     # .copy(): writable, and decoupled from the (much larger) frame buffer
     arr = np.frombuffer(data, dtype=dtype, offset=off).reshape(shape).copy()
     if flags & _FLAG_JAX:
-        import jax.numpy as jnp
-
         return jnp.asarray(arr)
     return arr
 
@@ -136,13 +129,8 @@ class _WirePickler(pickle.Pickler):
             return (_load_dead, ())
         if isinstance(obj, (np.ndarray, np.generic)):
             return (decode_tensor, (encode_tensor(obj),))
-        try:
-            import jax
-
-            if isinstance(obj, jax.Array):
-                return (decode_tensor, (encode_tensor(obj),))
-        except ImportError:  # pragma: no cover - jax is a hard dep elsewhere
-            pass
+        if isinstance(obj, jax.Array):
+            return (decode_tensor, (encode_tensor(obj),))
         return NotImplemented
 
 

@@ -587,13 +587,17 @@ def start_worker_processes(
     standby is a worker spawned with the next free task id, registered
     into the pool only when recovery re-places a dead task onto it.
     ``extra_env`` overlays the inherited environment (e.g. a seeded
-    ``REPRO_FAULTS`` plan shipped to every process of the pool).
+    ``REPRO_FAULTS`` plan shipped to every process of the pool).  Workers
+    run on the CPU (``JAX_PLATFORMS=cpu``), so a parent that holds a chip
+    can spawn them.
     """
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the pool is a CPU path: a worker must never contend for the chip
+    # its parent may hold, whatever JAX_PLATFORMS the parent runs with
+    env["JAX_PLATFORMS"] = "cpu"
     if extra_env:
         env.update(extra_env)
     procs: List[subprocess.Popen] = []

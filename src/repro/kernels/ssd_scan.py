@@ -9,7 +9,13 @@ TPU-native shape of the SSD algorithm (DESIGN.md: recurrent-scan
 blocking for VMEM instead of the paper's CUDA warp layout).
 
 Layouts (heads folded): x (BH, S, P), dt (BH, S), Bc/Cc (BH, S, N),
-A (BH,) negative decay rate per head.
+A (BH,) negative decay rate per head.  Inside the call dt is a column,
+(BH, S, 1), so its (chunk, 1) blocks meet the TPU's (8, 128) tiling rule
+(the last dim equals the array's), and A rides scalar prefetch into SMEM.
+Prefix sums are matmuls with a triangular ones matrix, which the MXU
+runs and the TPU compiler accepts; the intra-chunk weights dt_t scale
+the rows of x rather than the columns of the score matrix, so no row
+copy of dt is needed.
 """
 from __future__ import annotations
 
@@ -21,41 +27,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_scr, *,
                 chunk: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)      # (Q,)
-    a = a_ref[0].astype(jnp.float32)        # scalar (negative)
+    dt = dt_ref[0].astype(jnp.float32)      # (Q, 1)
+    a = a_ref[pl.program_id(0)]             # scalar (negative)
     B = b_ref[0].astype(jnp.float32)        # (Q, N)
     C = c_ref[0].astype(jnp.float32)        # (Q, N)
 
-    dA = dt * a                             # (Q,)
-    cs = jnp.cumsum(dA)                     # (Q,)
-    # intra-chunk: att[q,t] = C_q·B_t * exp(cs_q - cs_t) * dt_t, t<=q
-    seg = cs[:, None] - cs[None, :]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tri, jnp.exp(seg), 0.0)
+    dA = dt * a                             # (Q, 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = rows >= cols                      # tri[q, t] = t <= q
+    # inclusive prefix sums of dA, as a column and as a row
+    cs = jnp.dot(tri.astype(jnp.float32), dA,
+                 preferred_element_type=jnp.float32)          # (Q, 1)
+    cs_row = jax.lax.dot_general(
+        dA, (rows <= cols).astype(jnp.float32), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # (1, Q)
+    # intra-chunk: y_q = sum_{t<=q} C_q·B_t * exp(cs_q - cs_t) * dt_t x_t
+    L = jnp.where(tri, jnp.exp(cs - cs_row), 0.0)
     att = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    att = att * L * dt[None, :]
-    y = jax.lax.dot(att, x, preferred_element_type=jnp.float32)
+    y = jnp.dot(att * L, dt * x, preferred_element_type=jnp.float32)
 
     # inter-chunk: y += (C * exp(cs)) @ state   (state: (N, P))
-    y += jax.lax.dot(C * jnp.exp(cs)[:, None], state_scr[...],
-                     preferred_element_type=jnp.float32)
+    y += jnp.dot(C * jnp.exp(cs), state_scr[...],
+                 preferred_element_type=jnp.float32)
 
     # state update: state = exp(cs_end) * state + sum_t w_t B_t^T x_t
-    cs_end = cs[chunk - 1]
-    w = dt * jnp.exp(cs_end - cs)           # (Q,)
-    Bw = B * w[:, None]                     # (Q, N)
+    cs_end = jnp.sum(dA, axis=0, keepdims=True)               # (1, 1)
+    w = dt * jnp.exp(cs_end - cs)           # (Q, 1)
     state_scr[...] = (jnp.exp(cs_end) * state_scr[...]
                       + jax.lax.dot_general(
-                          Bw, x, (((0,), (0,)), ((), ())),
+                          B * w, x, (((0,), (0,)), ((), ())),
                           preferred_element_type=jnp.float32))
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -72,16 +81,18 @@ def ssd_scan_pallas(x: jax.Array, dt: jax.Array, a: jax.Array,
     n_chunks = S // chunk
     return pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
-        grid=(BH, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, P), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, c: (bh, c)),
-            pl.BlockSpec((1,), lambda bh, c: (bh,)),
-            pl.BlockSpec((1, chunk, N), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda bh, c: (bh, c, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk, P), lambda bh, c: (bh, c, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, n_chunks),
+            in_specs=[
+                pl.BlockSpec((1, chunk, P), lambda bh, c, a: (bh, c, 0)),
+                pl.BlockSpec((1, chunk, 1), lambda bh, c, a: (bh, c, 0)),
+                pl.BlockSpec((1, chunk, N), lambda bh, c, a: (bh, c, 0)),
+                pl.BlockSpec((1, chunk, N), lambda bh, c, a: (bh, c, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, chunk, P), lambda bh, c, a: (bh, c, 0)),
+            scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
-        scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, Bc, Cc)
+    )(a.astype(jnp.float32), x, dt.reshape(BH, S, 1), Bc, Cc)

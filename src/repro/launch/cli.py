@@ -9,9 +9,41 @@ place — the options object then applies the documented resolution order
 from __future__ import annotations
 
 import argparse
-from typing import Optional
+import os
+
+import jax
 
 from ..core.options import SessionOptions
+
+#: The checkout this package runs from (``<checkout>/src/repro/launch``).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache across processes; returns
+    its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it
+    is (JAX reads it itself); otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache`` (the path is part of every entry's key, so
+    it must not move between runs).  Entry points call this from their
+    ``main``; importing a module never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def add_model_options(ap: argparse.ArgumentParser, *, arch: str
+                      ) -> argparse.ArgumentParser:
+    """--arch / --smoke: which model, at published widths unless --smoke."""
+    ap.add_argument("--arch", default=arch)
+    ap.add_argument("--smoke", action="store_true",
+                    help="run the architecture's reduced smoke preset "
+                         "(<= 2 layers, small widths) instead of its "
+                         "published widths")
+    return ap
 
 
 def add_engine_options(ap: argparse.ArgumentParser,

@@ -29,6 +29,10 @@ from . import mesh as mesh_mod
 from . import roofline as roofline_mod
 from .steps import build_step
 
+# the chip the production meshes are made of; its peaks come from
+# roofline.PEAKS (the host devices this script compiles on are stand-ins)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
@@ -60,6 +64,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     mem = compiled.memory_analysis()
     rl = roofline_mod.analyze(compiled, arch=arch, shape=shape,
                               mesh_name=mesh_name, n_devices=n_dev,
+                              device_kind=TARGET_DEVICE_KIND,
                               cfg=cfg, model=bundle.model)
     record = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name,

@@ -14,46 +14,21 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 
 
-# Older jax (< 0.5) has no jax.sharding.AxisType and jax.make_mesh takes
-# no axis_types kwarg; every axis behaves as Auto there, so building the
-# mesh untyped is semantics-preserving.  Gate on the attribute instead of
-# a version string (the attribute is the actual dependency).
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
-def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def host_mesh_supported() -> bool:
-    """True iff this jax can build the degenerate 1x1 host mesh (used by
-    CPU tests of the sharded path to skip cleanly on exotic versions)."""
-    try:
-        make_host_mesh()
-        return True
-    except (AttributeError, TypeError):
-        # only the known version incompatibilities (missing AxisType /
-        # make_mesh signature drift) downgrade to a skip — anything else
-        # propagates so a broken sharded path fails loudly, not silently
-        return False
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """A mesh over ``jax.devices()``, e.g. (data=2, model=2) on a v5e 2x2."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh for CPU tests of the sharded code path."""
-    return _make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def mesh_rules(mesh, *, overrides: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:

@@ -3,9 +3,12 @@
 Per (arch × shape × mesh) we derive three per-step time lower bounds from
 the SPMD-partitioned per-device HLO module:
 
-    compute_s    = HLO_FLOPs_per_device / PEAK_FLOPS          (197 TF/s bf16)
-    memory_s     = HLO_bytes_per_device / HBM_BW              (819 GB/s)
-    collective_s = collective_bytes_per_device / LINK_BW      (~50 GB/s/link)
+    compute_s    = HLO_FLOPs_per_device / peak FLOP/s
+    memory_s     = HLO_bytes_per_device / peak HBM bytes/s
+    collective_s = collective_bytes_per_device / peak bytes/s per link
+
+with the peaks of the target chip taken from :data:`PEAKS` by its
+``device_kind``.
 
 FLOPs, HBM traffic and collective wire bytes come from the trip-count-
 aware HLO analyzer (hlo_analysis.py) over the SPMD-partitioned module —
@@ -24,9 +27,22 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-PEAK_FLOPS = 197e12      # bf16 per chip, TPU v5e
-HBM_BW = 819e9           # bytes/s per chip
-LINK_BW = 50e9           # bytes/s per ICI link
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e (kind
+#: "TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+#: bf16, 819 GB/s of HBM bandwidth, and 1,600 Gbit/s of interchip
+#: interconnect over four links (50 GB/s per link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table of one chip kind; a kind not in :data:`PEAKS` is an
+    error, never another chip's numbers."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak table for device kind {device_kind!r}; "
+                       f"known kinds: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -84,6 +100,7 @@ class Roofline:
     shape: str
     mesh: str
     n_devices: int
+    device_kind: str
     flops_per_device: float
     bytes_per_device: float
     collective_bytes_per_device: float
@@ -97,9 +114,10 @@ class Roofline:
     collective_s: float = 0.0
 
     def __post_init__(self):
-        self.compute_s = self.flops_per_device / PEAK_FLOPS
-        self.memory_s = self.bytes_per_device / HBM_BW
-        self.collective_s = self.collective_bytes_per_device / LINK_BW
+        peak = peaks(self.device_kind)
+        self.compute_s = self.flops_per_device / peak["flops"]
+        self.memory_s = self.bytes_per_device / peak["hbm_bw"]
+        self.collective_s = self.collective_bytes_per_device / peak["link_bw"]
 
     @property
     def dominant(self) -> str:
@@ -149,7 +167,7 @@ def active_params(cfg, model) -> int:
 
 
 def analyze(compiled, *, arch: str, shape, mesh_name: str, n_devices: int,
-            cfg, model) -> Roofline:
+            device_kind: str, cfg, model) -> Roofline:
     from .hlo_analysis import analyze_text
 
     ca = compiled.cost_analysis() or {}
@@ -157,6 +175,7 @@ def analyze(compiled, *, arch: str, shape, mesh_name: str, n_devices: int,
     st = analyze_text(compiled.as_text(), n_devices)
     return Roofline(
         arch=arch, shape=shape.name, mesh=mesh_name, n_devices=n_devices,
+        device_kind=device_kind,
         flops_per_device=st.flops,
         bytes_per_device=st.hbm_bytes,
         collective_bytes_per_device=st.collective_bytes,

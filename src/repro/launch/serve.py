@@ -19,7 +19,8 @@ from ..core.options import SessionOptions
 from ..models.api import Model, Shape
 from ..models.params import init_params
 from ..obs import metrics as obs_metrics
-from .cli import add_cluster_options, add_engine_options, add_obs_options
+from .cli import (add_cluster_options, add_engine_options, add_model_options,
+                  add_obs_options, enable_compile_cache)
 from .steps import build_serve_step, build_eager_serve_step
 
 
@@ -38,7 +39,7 @@ def print_metrics(label: str = "serve") -> None:
     print(f"[{label}] metrics: " + ("; ".join(parts) or "empty"))
 
 
-def serve(arch: str = "qwen2-0.5b", *, smoke: bool = True, batch: int = 4,
+def serve(arch: str = "qwen2-0.5b", *, smoke: bool = False, batch: int = 4,
           prompt_len: int = 16, gen: int = 32, max_seq: int = 128,
           seed: int = 0, temperature: float = 0.0,
           engine: str = "jit", numerics: str = "fast",
@@ -48,7 +49,11 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = True, batch: int = 4,
     every token re-runs one cached Executable (DESIGN.md §5).  The graph
     engine defaults to ``numerics="fast"`` (the decode Call + cache Assign
     fuse into one region at full XLA optimization, §9 tolerance contract);
-    ``numerics="strict"`` restores bit-parity with unfused execution."""
+    ``numerics="strict"`` restores bit-parity with unfused execution.
+
+    Returns the ``generated`` token ids, the ``prompts`` and
+    ``prompt_logits`` — the logits at the last prompt position, as the
+    cache-filling steps produced them — plus prefill/decode timings."""
     cfg = get_config(arch, smoke=smoke)
     model = Model.for_config(cfg)
     params = model.init(jax.random.PRNGKey(seed))
@@ -82,7 +87,12 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = True, batch: int = 4,
             logits = eb.step({"tokens": tk.astype(jnp.int32), "pos": t})
             return logits, c
     else:
-        step = jax.jit(lambda c, tk, t: model.serve_step(params, c, tk, t))
+        # params go in as an argument: closed over, they would be baked
+        # into the executable as constants (2.5 GB for qwen2-0.5b)
+        decode = jax.jit(model.serve_step)
+
+        def step(c, tk, t):
+            return decode(params, c, tk, t)
 
     # --- prefill: feed prompt tokens one step at a time (the cache fills);
     # production prefill lowers the batched forward (launch/steps.py).
@@ -91,6 +101,7 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = True, batch: int = 4,
     for t in range(prompt_len):
         logits, cache = step(cache, prompts[:, t:t + 1], jnp.array(t))
     prefill_s = time.time() - t0
+    prompt_logits = logits
 
     # --- decode: greedy (or temperature) sampling, batched
     out_tokens = []
@@ -114,7 +125,8 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = True, batch: int = 4,
           f"{'/' + numerics if engine == 'graph' else ''} batch={batch} "
           f"prefill {prefill_s:.2f}s "
           f"decode {decode_s:.2f}s ({tput:.1f} tok/s)")
-    res = {"generated": gen_arr, "prefill_s": prefill_s,
+    res = {"generated": gen_arr, "prompts": prompts,
+           "prompt_logits": prompt_logits, "prefill_s": prefill_s,
            "decode_s": decode_s, "tokens_per_s": tput}
     if eb is not None:
         res["executable_cache"] = eb.session.cache_stats
@@ -177,8 +189,7 @@ def serve_cluster(cluster: str, *, batch: int = 32, requests: int = 100,
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    add_model_options(ap, arch="qwen2-0.5b")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=32)
@@ -188,6 +199,7 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=100,
                     help="number of scoring requests in --cluster mode")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.cluster:
         serve_cluster(args.cluster, batch=args.batch, requests=args.requests,
                       trace_dir=args.trace_dir,

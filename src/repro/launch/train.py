@@ -28,11 +28,12 @@ from ..models.api import Shape
 from ..models.params import init_params, count_params
 from ..obs import metrics as obs_metrics
 from ..optim import adamw_init
-from .cli import add_cluster_options, add_engine_options, add_obs_options
+from .cli import (add_cluster_options, add_engine_options, add_model_options,
+                  add_obs_options, enable_compile_cache)
 from .steps import build_train_step, build_eager_train_step
 
 
-def train(arch: str = "smollm-360m", *, smoke: bool = True, steps: int = 200,
+def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
           batch: int = 8, seq: int = 256, lr: float = 1e-3,
           ckpt_dir: Optional[str] = None, ckpt_every: int = 100,
           log_every: int = 10, seed: int = 0,
@@ -48,17 +49,28 @@ def train(arch: str = "smollm-360m", *, smoke: bool = True, steps: int = 200,
     ``numerics="fast"`` — fused regions (incl. matmuls/reductions) compile
     at full XLA optimization under the §9 tolerance contract enforced by
     the CI parity gate; ``numerics="strict"`` restores bit-parity with
-    unfused execution."""
+    unfused execution.
+
+    Returns the per-step ``losses``, the per-step wall seconds
+    (``step_seconds``, each timed to the step's outputs being ready; the
+    first includes compilation) and the final training state
+    (``variables``: ``params`` and ``opt``)."""
     cfg = get_config(arch, smoke=smoke)
     shape = Shape("custom", seq, batch, "train")
     hparam_overrides = {"compute_dtype": jnp.float32,
                         "loss_chunk": 0, "q_chunk": 0}
     eb = None
     if engine == "graph":
+        # parity_guard off, as for train_replicated: AdamW's first update
+        # is ~sign(grad), so reassociation noise in near-zero gradients
+        # moves parameters by up to 2*lr — a drift no per-op-class
+        # tolerance admits, and the guard's strict fallback would then run
+        # every step unfused; --numerics strict remains the bit-exact path
         eb = build_eager_train_step(cfg, shape, lr=lr,
                                     hparam_overrides=hparam_overrides,
                                     numerics=numerics,
-                                    options=SessionOptions(backend=backend))
+                                    options=SessionOptions(
+                                        backend=backend, parity_guard=False))
         model, graph_nodes = eb.model, eb.graph_nodes
     else:
         sb = build_train_step(cfg, shape, lr=lr,
@@ -98,6 +110,7 @@ def train(arch: str = "smollm-360m", *, smoke: bool = True, steps: int = 200,
     if engine == "graph":
         for name, value in variables.items():
             eb.session.set_variable(name, value)
+        variables = {}  # the Session's store holds the only copy
 
     def snapshot_variables() -> Dict[str, Any]:
         return eb.variables() if engine == "graph" else variables
@@ -112,6 +125,7 @@ def train(arch: str = "smollm-360m", *, smoke: bool = True, steps: int = 200,
         writer = SummaryWriter(summary_dir or os.path.join(ckpt_dir, "events"))
 
     losses = []
+    step_seconds = []
     t0 = time.time()
     for i in range(start_step, steps):
         raw = pipe.get()
@@ -122,11 +136,13 @@ def train(arch: str = "smollm-360m", *, smoke: bool = True, steps: int = 200,
                 (batch, cfg.enc_seq, cfg.d_model), jnp.float32)
         t_step = time.time()
         loss, variables = step_fn(feeds, variables)
+        jax.block_until_ready((loss, variables))
+        step_seconds.append(time.time() - t_step)
         losses.append(float(loss))
         if writer:
             writer.add(i + 1, "train/loss", losses[-1])
             writer.add(i + 1, "train/tokens_per_sec",
-                       batch * seq / max(time.time() - t_step, 1e-9))
+                       batch * seq / max(step_seconds[-1], 1e-9))
         if mgr and mgr.should_save(i + 1):
             mgr.save(i + 1, {"variables": snapshot_variables()})
         if (i + 1) % log_every == 0:
@@ -146,7 +162,9 @@ def train(arch: str = "smollm-360m", *, smoke: bool = True, steps: int = 200,
         mgr.save(steps, {"variables": snapshot_variables()})
     out: Dict[str, Any] = {"losses": losses,
                            "final_loss": losses[-1] if losses else None,
-                           "params": n_params}
+                           "params": n_params,
+                           "step_seconds": step_seconds,
+                           "variables": snapshot_variables()}
     if engine == "graph":
         out["executable_cache"] = eb.session.cache_stats
     return out
@@ -358,7 +376,7 @@ def train_cluster(cluster: str, *, steps: int = 50, batch: int = 64,
 
 
 def train_replicated(cluster: Optional[str], *, arch: str = "smollm-360m",
-                     smoke: bool = True, replicas: int = 4,
+                     smoke: bool = False, replicas: int = 4,
                      mode: str = "sync", steps: int = 30, batch: int = 8,
                      seq: int = 64, lr: float = 1e-2, log_every: int = 5,
                      seed: int = 0, numerics: str = "fast",
@@ -438,9 +456,7 @@ def train_replicated(cluster: Optional[str], *, arch: str = "smollm-360m",
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--smoke", action="store_true", default=False)
-    ap.add_argument("--full", dest="smoke", action="store_false")
+    add_model_options(ap, arch="smollm-360m")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
@@ -450,8 +466,8 @@ def main(argv=None) -> int:
     add_engine_options(ap)
     add_cluster_options(ap, replication=True, standby=True)
     add_obs_options(ap, summary=True)
-    ap.set_defaults(smoke=True)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.cluster and args.replicas > 1:
         res = train_replicated(args.cluster, arch=args.arch, smoke=args.smoke,
                                replicas=args.replicas, mode=args.mode,

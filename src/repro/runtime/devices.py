@@ -13,9 +13,18 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
+import jax
+
 _DEV_RE = re.compile(
     r"^/job:(?P<job>[a-z0-9_]+)(/task:(?P<task>\d+))?/device:(?P<kind>[a-z]+):(?P<index>\d+)$"
 )
+
+
+def local_kind() -> str:
+    """The kind of device this process computes on ("cpu", "gpu" or
+    "tpu"): JAX's default backend.  The single-device runtime names its
+    device by it, so tolerance tables and kernels follow the hardware."""
+    return jax.default_backend()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +64,7 @@ class Device:
 class DeviceSet:
     def __init__(self, devices: Optional[List[Device]] = None) -> None:
         self.devices: Dict[str, Device] = {}
-        for d in devices or [Device(DeviceName())]:
+        for d in devices or [Device(DeviceName(kind=local_kind()))]:
             self.devices[str(d.name)] = d
 
     @staticmethod

@@ -1,69 +1,8 @@
 import os
 import sys
-import types
 
 # Tests run single-device (the dry-run pins 512 host devices in its own
 # process; never here).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-
-def _install_hypothesis_stub() -> None:
-    """Make ``hypothesis`` optional: in offline environments the 5
-    property-based test modules must still *collect* — ``@given`` tests
-    skip cleanly and every plain test in those modules keeps running."""
-    import pytest
-
-    class _Strategy:
-        """Inert stand-in for any hypothesis strategy object."""
-
-        def __call__(self, *a, **k):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-    def given(*_a, **_k):
-        def deco(fn):
-            # deliberately NOT functools.wraps: the wrapper must expose a
-            # no-argument signature so pytest doesn't treat the strategy
-            # parameters as missing fixtures
-            def skipper(*args, **kwargs):
-                pytest.skip("hypothesis not installed; property-based test skipped")
-
-            skipper.__name__ = getattr(fn, "__name__", "hypothesis_test")
-            skipper.__doc__ = fn.__doc__
-            return skipper
-
-        return deco
-
-    def settings(*_a, **_k):
-        def deco(fn):
-            return fn
-
-        return deco
-
-    settings.register_profile = lambda *a, **k: None
-    settings.load_profile = lambda *a, **k: None
-
-    strategies = types.ModuleType("hypothesis.strategies")
-    strategies.__getattr__ = lambda name: _Strategy()
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = strategies
-    mod.assume = lambda *a, **k: True
-    mod.note = lambda *a, **k: None
-    mod.HealthCheck = _Strategy()
-    mod.__is_repro_stub__ = True
-
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_stub()

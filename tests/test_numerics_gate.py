@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.core import GraphBuilder, Session, TensorRef, register
 from repro.core import numerics as num
 from repro.core.graph import as_ref
+from repro.obs import metrics as obs_metrics
 
 
 # ---------------------------------------------------------------------------
@@ -130,6 +131,9 @@ def test_tolerance_table_device_and_backend_keying():
     # merging across device kinds keeps the loosest bound
     t = num.tolerance_for_ops({"MatMul"}, device_kinds=("cpu", "tpu"))
     assert t.ulp == tpu["matmul"].ulp
+    # an uncalibrated device kind is an error, never the CPU table
+    with pytest.raises(KeyError, match="no tolerance table"):
+        num.tolerance_table("npu")
 
 
 # ---------------------------------------------------------------------------
@@ -193,8 +197,11 @@ def test_session_fallback_on_breach_matches_strict_bitwise():
     fast = Session(b.graph, numerics="fast")  # parity guard defaults on
     strict = Session(b.graph, numerics="strict", fuse_regions=False)
     feeds = lambda: {y.ref: jnp.asarray(CANCEL_INPUT)}  # noqa: E731
+    demotions = obs_metrics.counter("numerics.guard_demotions")
+    before = demotions.value
     with pytest.warns(RuntimeWarning, match="parity breach"):
         fv = fast.run([fin.ref, upd.ref], feeds())
+    assert demotions.value == before + 1  # the demotion is counted
     sv = strict.run([fin.ref, upd.ref], feeds())
     assert [float(a) for a in fv] == [float(c) for c in sv]
     assert float(fast.variable_value("v")) == float(

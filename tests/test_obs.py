@@ -314,8 +314,10 @@ def test_train_summary_dir_round_trip(tmp_path):
     from repro.launch.train import train
     from repro.tools.summary import read_events
 
-    train(steps=3, batch=2, seq=16, log_every=10,
-          summary_dir=str(tmp_path / "sum"))
+    res = train(smoke=True, steps=3, batch=2, seq=16, log_every=10,
+                summary_dir=str(tmp_path / "sum"))
+    assert len(res["step_seconds"]) == 3
+    assert set(res["variables"]) == {"params", "opt"}
     events = read_events(str(tmp_path / "sum"))
     assert len(events["train/loss"]) == 3
     assert len(events["train/tokens_per_sec"]) == 3

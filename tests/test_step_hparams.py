@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import get_config
 from repro.launch.steps import build_step
@@ -72,10 +71,6 @@ def test_seq_res_rules_preserve_loss_on_host_mesh():
     """SP sharding rules are semantics-preserving (1x1 mesh sanity)."""
     from repro.launch import mesh as mesh_mod
     from repro.parallel import sharding as shd
-
-    if not mesh_mod.host_mesh_supported():
-        pytest.skip("this jax cannot build the 1x1 host mesh "
-                    "(launch/mesh.py gate)")
 
     cfg = get_config("smollm-360m", smoke=True)
     feeds = _feeds(cfg)

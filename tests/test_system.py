@@ -9,7 +9,6 @@ path step for step.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.checkpoint import CheckpointManager, FileCheckpointIO
 from repro.configs import get_config
@@ -97,9 +96,6 @@ def test_sharded_jit_path_on_host_mesh():
     from repro.launch import mesh as mesh_mod
     from repro.parallel import sharding as shd
 
-    if not mesh_mod.host_mesh_supported():
-        pytest.skip("this jax cannot build the 1x1 host mesh "
-                    "(launch/mesh.py gate)")
     cfg = get_config("smollm-360m", smoke=True)
     mesh = mesh_mod.make_host_mesh()
     rules = mesh_mod.mesh_rules(mesh)
