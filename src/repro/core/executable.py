@@ -360,6 +360,8 @@ class Executable:
         self._parity_pending = False
         self._guard_lock = threading.Lock()
         self._guard_vars: List[str] = []
+        self._guard_update_vars: List[str] = []
+        self._guard_update_tol = 0.0
         self._guard_tol = None
         self._guard_every: Optional[int] = None
         self._guard_runs = 0
@@ -388,6 +390,13 @@ class Executable:
                          for d in self.placement.values()})) or kinds
                 self._guard_tol = numerics_mod.tolerance_for_ops(
                     ops, device_kinds=kinds, backend=self.kernel_backend)
+                # parameters an optimizer rewrites are judged by their
+                # update, not elementwise (numerics.UPDATE_TOLERANCE)
+                self._guard_update_vars = sorted(
+                    numerics_mod.optimizer_written_variables(
+                        session.graph, self.node_set)
+                    & set(self._guard_vars))
+                self._guard_update_tol = numerics_mod.update_tolerance(kinds)
                 self._guard_every = getattr(session, "parity_guard_every", None)
 
     # ------------------------------------------------------------------
@@ -504,45 +513,43 @@ class Executable:
                                              timeout=timeout)
                 return self._dispatch(feeds, trace=trace, tracer=tracer,
                                       spans=spans, timeout=timeout)
-            from . import numerics as numerics_mod
+            import jax
 
             store = self.session.variables
             g = self.session.graph
             # force-init so both executions observe identical initial state
             snap = {n: store.read(n, g.nodes[n].attrs)
                     for n in self._guard_vars}
+            before = jax.device_get({n: snap[n]
+                                     for n in self._guard_update_vars})
             ref = self._run_unfused(feeds, trace=None, tracer=None,
                                     timeout=timeout)
-            ref_vars = {n: store.read(n, g.nodes[n].attrs)
-                        for n in self._guard_vars}
+            # held on the host, so the device keeps one copy of the state
+            # through the fast run, not three
+            ref_vars = jax.device_get({n: store.read(n, g.nodes[n].attrs)
+                                       for n in self._guard_vars})
             for n, v in snap.items():
                 store.write(n, v)
+            del snap
             got = self._dispatch(feeds, trace=trace, tracer=tracer,
                                  spans=spans, timeout=timeout)
             got_vars = {n: store.read(n, g.nodes[n].attrs)
                         for n in self._guard_vars}
-            # elementwise either-criterion (compare), NOT an aggregate
-            # max-drift check: max ULP and max rel may come from
-            # different tensors that each pass on their own bound —
-            # merging them first would demote spuriously
-            ok, drift = numerics_mod.compare(
-                list(ref) + [ref_vars[n] for n in self._guard_vars],
-                list(got) + [got_vars[n] for n in self._guard_vars],
-                self._guard_tol)
+            ok, drift = self._guard_verdict(ref, got, ref_vars, got_vars,
+                                            before)
             if not ok:
                 import warnings
 
                 warnings.warn(
                     f"fast-numerics parity breach: fused-fast drifted "
-                    f"{drift} from the unfused-strict reference, beyond "
-                    f"the {self._guard_tol} tolerance for this graph's op "
-                    f"classes; falling back to strict execution for "
-                    f"fetches {[str(r) for r in self.fetches]} "
-                    f"(DESIGN.md §9)", RuntimeWarning, stacklevel=3)
+                    f"{drift} from the unfused-strict reference; falling "
+                    f"back to strict execution for fetches "
+                    f"{[str(r) for r in self.fetches]} (DESIGN.md §9)",
+                    RuntimeWarning, stacklevel=3)
                 self._strict_fallback = True
                 metrics_mod.counter("numerics.guard_demotions").inc()
                 for n, v in ref_vars.items():
-                    store.write(n, v)
+                    store.write(n, jax.device_put(v))
                 # cleared only with the verdict, inside the lock: an
                 # early clear would let a concurrent run() slip past the
                 # guard unverified and race the comparison; and if either
@@ -552,6 +559,37 @@ class Executable:
                 return ref
             self._parity_pending = False
             return got
+
+    def _guard_verdict(self, ref: Sequence[Any], got: Sequence[Any],
+                       ref_vars: Dict[str, Any], got_vars: Dict[str, Any],
+                       before: Dict[str, Any]) -> Tuple[bool, str]:
+        """Judge one guarded run: fetches and variables by the §9
+        per-class tolerance, variables an optimizer rewrote (the keys of
+        ``before``, their pre-run values) by their update drift.  Returns
+        (ok, a description of the drift against its bounds)."""
+        from . import numerics as numerics_mod
+
+        plain = sorted(set(ref_vars) - set(before))
+        upd = sorted(before)
+        # elementwise either-criterion (compare), NOT an aggregate
+        # max-drift check: max ULP and max rel may come from different
+        # tensors that each pass on their own bound — merging them first
+        # would demote spuriously
+        ok, drift = numerics_mod.compare(
+            list(ref) + [ref_vars[n] for n in plain],
+            list(got) + [got_vars[n] for n in plain], self._guard_tol)
+        metrics_mod.counter("numerics.guard_checks").inc()
+        metrics_mod.gauge("numerics.guard_rel_drift").set(drift.rel)
+        desc = f"{drift} against {self._guard_tol}"
+        if upd:
+            u = numerics_mod.update_drift([before[n] for n in upd],
+                                          [ref_vars[n] for n in upd],
+                                          [got_vars[n] for n in upd])
+            metrics_mod.gauge("numerics.guard_update_drift").set(u)
+            ok = ok and u <= self._guard_update_tol
+            desc += (f", update drift {u:.3g} of {upd} against "
+                     f"{self._guard_update_tol:g}")
+        return ok, desc
 
     # ------------------------------------------------------------------
     def _wire_strict_plan(self):
@@ -585,8 +623,6 @@ class Executable:
                     return self._wire_strict_plan().run(feeds, timeout=timeout,
                                                         spans=spans)
                 return self.wire_plan.run(feeds, timeout=timeout, spans=spans)
-            from . import numerics as numerics_mod
-
             plan = self.wire_plan
             strict = self._wire_strict_plan()
             # register (and SEED Variables) before snapshotting: on the
@@ -600,20 +636,19 @@ class Executable:
             plan.restore_variables(snap)
             got = plan.run(feeds, timeout=timeout, spans=spans)
             got_vars = plan.snapshot_variables(self._guard_vars)
-            names = sorted(set(ref_vars) & set(got_vars))
-            ok, drift = numerics_mod.compare(
-                list(ref) + [ref_vars[n] for n in names],
-                list(got) + [got_vars[n] for n in names],
-                self._guard_tol)
+            names = set(ref_vars) & set(got_vars)
+            ok, drift = self._guard_verdict(
+                ref, got, {n: ref_vars[n] for n in names},
+                {n: got_vars[n] for n in names},
+                {n: snap[n] for n in self._guard_update_vars if n in names})
             if not ok:
                 import warnings
 
                 warnings.warn(
                     f"fast-numerics parity breach (distributed): fused-fast "
-                    f"drifted {drift} from the strict wire reference, beyond "
-                    f"the {self._guard_tol} tolerance for this graph's op "
-                    f"classes; falling back to strict wire execution for "
-                    f"fetches {[str(r) for r in self.fetches]} "
+                    f"drifted {drift} from the strict wire reference; "
+                    f"falling back to strict wire execution for fetches "
+                    f"{[str(r) for r in self.fetches]} "
                     f"(DESIGN.md §9/§13)", RuntimeWarning, stacklevel=3)
                 self._strict_fallback = True
                 metrics_mod.counter("numerics.guard_demotions").inc()

@@ -124,7 +124,15 @@ class Prefetcher:
     def _fill(self) -> None:
         try:
             for item in self._source:
-                self.queue.enqueue(item)
+                while True:
+                    try:
+                        self.queue.enqueue(item)
+                        break
+                    except TimeoutError:
+                        # a full queue is back-pressure from a slow
+                        # consumer (a step that compiles for minutes),
+                        # not a fault: keep waiting
+                        continue
                 # Yield the GIL right after publishing: a consumer blocked
                 # in dequeue() was just notified, but without an explicit
                 # yield the producer keeps the GIL for up to the switch

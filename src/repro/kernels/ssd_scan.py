@@ -43,12 +43,15 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_scr, *,
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     tri = rows >= cols                      # tri[q, t] = t <= q
-    # inclusive prefix sums of dA, as a column and as a row
-    cs = jnp.dot(tri.astype(jnp.float32), dA,
+    # inclusive prefix sums of dA, as a column and as a row, at full f32
+    # precision: they are exponentiated, so a bf16 pass would put errors
+    # of ~2**-9 * |cs| into the decay exponents
+    exact = jax.lax.Precision.HIGHEST
+    cs = jnp.dot(tri.astype(jnp.float32), dA, precision=exact,
                  preferred_element_type=jnp.float32)          # (Q, 1)
     cs_row = jax.lax.dot_general(
         dA, (rows <= cols).astype(jnp.float32), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # (1, Q)
+        precision=exact, preferred_element_type=jnp.float32)  # (1, Q)
     # intra-chunk: y_q = sum_{t<=q} C_q·B_t * exp(cs_q - cs_t) * dt_t x_t
     L = jnp.where(tri, jnp.exp(cs - cs_row), 0.0)
     att = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),

@@ -20,6 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import (GraphBuilder, Session, SessionOptions, compile_subgraph,
                     gradients)
+from ..core import numerics
 from ..models.api import Model, Shape, SHAPES
 from ..models.config import ModelConfig
 from ..models.params import abstract_params, param_axes, init_params
@@ -160,6 +161,11 @@ def lm_loss_and_grad_factory(cfg: ModelConfig, shard: int, feed_names,
     return graph_loss_grad
 
 
+#: the AdamW apply's outputs: updated params (judged by their update, see
+#: numerics.UPDATE_TOLERANCE) and optimizer state
+ADAMW_ATTRS = {"numerics_class": (numerics.OPTIMIZER, "call")}
+
+
 def lm_update_factory(lr: float):
     """Rebuild the AdamW apply: ``(params, grads, opt) -> (params, opt)``."""
 
@@ -208,7 +214,8 @@ def _train_graph(feed_names, cfg: ModelConfig, shard: int, loss_kw,
                             name="loss_and_grad", n_out=2)
         loss_node, gref = lg, lg.output(1)
     upd = b.call_factory(LM_UPDATE_FACTORY, [v_params, gref, v_opt],
-                         args=(lr,), name="adamw", n_out=2)
+                         args=(lr,), name="adamw", n_out=2,
+                         attrs=ADAMW_ATTRS)
     a1 = b.assign(v_params, upd.output(0))
     a2 = b.assign(v_opt, upd.output(1))
     return b, loss_node, a1, a2, feed_nodes
@@ -734,7 +741,8 @@ def build_lm_replica_spec(cfg: ModelConfig, shape: Shape, *, lr: float = 1e-2,
         upd = b.call_factory(
             LM_UPDATE_FACTORY,
             [var_nodes["params"], mean_grads["params"], var_nodes["opt"]],
-            args=(lr,), name="adamw", n_out=2, device=dev)
+            args=(lr,), name="adamw", n_out=2, attrs=ADAMW_ATTRS,
+            device=dev)
         a1 = b.assign(var_nodes["params"], upd.output(0))
         a2 = b.assign(var_nodes["opt"], upd.output(1))
         return b.group([a1, a2], name="train_op")

@@ -33,6 +33,11 @@ from .cli import (add_cluster_options, add_engine_options, add_model_options,
 from .steps import build_train_step, build_eager_train_step
 
 
+#: The step hyperparameters every training entry point runs with: f32
+#: compute, no loss or query chunking.
+TRAIN_HPARAMS = {"compute_dtype": jnp.float32, "loss_chunk": 0, "q_chunk": 0}
+
+
 def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
           batch: int = 8, seq: int = 256, lr: float = 1e-3,
           ckpt_dir: Optional[str] = None, ckpt_every: int = 100,
@@ -57,24 +62,16 @@ def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
     (``variables``: ``params`` and ``opt``)."""
     cfg = get_config(arch, smoke=smoke)
     shape = Shape("custom", seq, batch, "train")
-    hparam_overrides = {"compute_dtype": jnp.float32,
-                        "loss_chunk": 0, "q_chunk": 0}
     eb = None
     if engine == "graph":
-        # parity_guard off, as for train_replicated: AdamW's first update
-        # is ~sign(grad), so reassociation noise in near-zero gradients
-        # moves parameters by up to 2*lr — a drift no per-op-class
-        # tolerance admits, and the guard's strict fallback would then run
-        # every step unfused; --numerics strict remains the bit-exact path
         eb = build_eager_train_step(cfg, shape, lr=lr,
-                                    hparam_overrides=hparam_overrides,
+                                    hparam_overrides=TRAIN_HPARAMS,
                                     numerics=numerics,
-                                    options=SessionOptions(
-                                        backend=backend, parity_guard=False))
+                                    options=SessionOptions(backend=backend))
         model, graph_nodes = eb.model, eb.graph_nodes
     else:
         sb = build_train_step(cfg, shape, lr=lr,
-                              hparam_overrides=hparam_overrides)
+                              hparam_overrides=TRAIN_HPARAMS)
         model, graph_nodes = sb.model, sb.graph_nodes
     n_params = count_params(model.describe_params())
     print(f"[train] arch={cfg.arch_id} engine={engine}"
@@ -399,8 +396,7 @@ def train_replicated(cluster: Optional[str], *, arch: str = "smollm-360m",
     shape = Shape("custom", seq, batch, "train")
     spec = build_lm_replica_spec(
         cfg, shape, lr=lr, seed=seed,
-        hparam_overrides={"compute_dtype": jnp.float32,
-                          "loss_chunk": 0, "q_chunk": 0})
+        hparam_overrides=TRAIN_HPARAMS)
     # parity_guard off: a whole fused train step (loss+grad+adamw+reduce)
     # legitimately drifts past the per-op-class §9 tolerance, and the
     # guard's strict fallback would serialize every step; --numerics

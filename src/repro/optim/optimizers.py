@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from ..core.graph import Node, TensorRef
 from ..core.ops import GraphBuilder
-from ..core import autodiff
+from ..core import autodiff, numerics
 
 
 class OptState(NamedTuple):
@@ -188,7 +188,9 @@ def attach_train_op(
                 p2 = p.astype(jnp.float32) - lr * (upd + wd * p.astype(jnp.float32))
                 return p2.astype(p.dtype), m2, v2
             res = b.call(adamw_node, [pv, gref, mvar, vvar, new_step],
-                         name=f"{name}/{pv.name}/adamw", n_out=3)
+                         name=f"{name}/{pv.name}/adamw", n_out=3,
+                         attrs={"numerics_class": (numerics.OPTIMIZER,
+                                                   "call", "call")})
             updates.append(b.assign(pv, res.output(0)))
             updates.append(b.assign(mvar, res.output(1)))
             updates.append(b.assign(vvar, res.output(2)))

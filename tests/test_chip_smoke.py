@@ -36,6 +36,7 @@ def test_chip_smoke_fails_without_a_chip():
 def test_chip_smoke_phases_at_smoke_size():
     losses = chip_smoke.phase_train(smoke=True, steps=4, batch=2, seq=32,
                                     platform="cpu")
+    chip_smoke.phase_kernels(smoke=True)
     chip_smoke.phase_serve(smoke=True, batch=2, prompt_len=8, gen=4)
     chip_smoke.phase_graph(losses[:3], smoke=True, steps=3, batch=2, seq=32)
 

@@ -1,5 +1,6 @@
 """§4.5/§4.6 input pipeline: readers, prefetch queues, determinism."""
 import os
+import time
 
 import numpy as np
 
@@ -41,6 +42,14 @@ def test_prefetcher_preserves_order_and_closes():
     src = iter(range(20))
     pf = Prefetcher(src, capacity=4).start()
     assert list(pf) == list(range(20))
+
+
+def test_prefetcher_outlasts_a_consumer_slower_than_the_queue_timeout():
+    pf = Prefetcher(iter(range(6)), capacity=2)
+    pf.queue.timeout = 0.05  # the producer's enqueue times out repeatedly
+    pf.start()
+    time.sleep(0.3)
+    assert list(pf) == list(range(6))
 
 
 def test_prefetcher_shuffling():

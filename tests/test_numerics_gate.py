@@ -136,6 +136,54 @@ def test_tolerance_table_device_and_backend_keying():
         num.tolerance_table("npu")
 
 
+@pytest.mark.parametrize("fault, want", [
+    ("exact", 0.0), ("missing", 1.0), ("doubled", 1.0), ("negated", 2.0),
+    ("one_flip_in_100", 2 * np.sqrt(1 / 100))])
+def test_update_drift_separates_faults_from_sign_flips(fault, want):
+    """An AdamW-like ~sign update: flipped elements cost 2*sqrt(k/n),
+    while a wrong update of the whole leaf costs O(1)."""
+    rs = np.random.RandomState(0)
+    before = rs.randn(100).astype("f")
+    step = 1e-3 * np.sign(rs.randn(100)).astype("f")
+    ref = before - step
+    got = {"exact": ref, "missing": before, "doubled": before - 2 * step,
+           "negated": before + step,
+           "one_flip_in_100": ref + 2 * step * (np.arange(100) == 7)}[fault]
+    assert num.update_drift([before], [ref], [got]) == pytest.approx(
+        want, rel=1e-3, abs=1e-6)
+    assert num.update_drift(before, ref, got * np.nan) == np.inf
+    with pytest.raises(KeyError, match="no update tolerance"):
+        num.update_tolerance(["npu"])
+
+
+@register("FlakyUpdate")
+def _flaky_update(ctx, node, p, g):
+    # the compiled (fused) path steps the wrong way
+    sign = -1.0 if isinstance(p, jax.core.Tracer) else 1.0
+    return (p - sign * 0.1 * g,)
+
+
+def test_guard_judges_optimizer_outputs_by_their_update():
+    """A variable set from an output declared "optimizer" is judged by
+    its update drift: an update in the wrong direction demotes."""
+    from repro.core.options import SessionOptions
+
+    b = GraphBuilder()
+    g = b.placeholder("g")
+    w = b.variable("w", init_value=lambda: jnp.ones(64, jnp.float32))
+    upd = b.graph.add_node("FlakyUpdate", [w, g], name="upd",
+                           attrs={"numerics_class": (num.OPTIMIZER,)})
+    train = b.group([b.assign(w, upd)], name="train")
+    assert num.optimizer_written_variables(b.graph, b.graph.nodes) == {"w"}
+    sess = Session(b.graph, options=SessionOptions(numerics="fast"))
+    with pytest.warns(RuntimeWarning, match=r"update drift 2 of \['w'\]"):
+        sess.run([train.ref], {g.ref: jnp.linspace(-1.0, 1.0, 64)})
+    # the reference state was kept: one step the right way
+    np.testing.assert_allclose(np.asarray(sess.variable_value("w")),
+                               1.0 - 0.1 * np.linspace(-1.0, 1.0, 64),
+                               rtol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # the gate itself
 

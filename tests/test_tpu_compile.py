@@ -97,12 +97,12 @@ def test_smollm_train_step_compiles_and_fits_v5e(one_chip):
     (f32 compute, batch 8x256) fits in one chip's HBM."""
     from repro.configs import get_config
     from repro.launch.steps import build_train_step
+    from repro.launch.train import TRAIN_HPARAMS
     from repro.models.api import Shape
 
     sb = build_train_step(get_config("smollm-360m"),
                           Shape("custom", 256, 8, "train"),
-                          hparam_overrides={"compute_dtype": jnp.float32,
-                                            "loss_chunk": 0, "q_chunk": 0})
+                          hparam_overrides=TRAIN_HPARAMS)
     compiled = jax.jit(sb.fn, donate_argnums=(1,)).lower(
         _on(one_chip, sb.feed_specs), _on(one_chip, sb.var_specs)).compile()
     mem = compiled.memory_analysis()
