@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - busy / window, from the profiler trace (bench/trace_reduce.py)."""
+
+
+def read(run):
+    tr = run.get("trace")
+    if not tr or tr["busy_s"] is None or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
