@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 
 import numpy as np
 
+from ..obs.spans import span
 from ..runtime.queues import FIFOQueue, QueueClosed, ShufflingQueue
 
 
@@ -152,7 +153,8 @@ class Prefetcher:
         return self
 
     def get(self) -> Any:
-        return self.queue.dequeue()
+        with span("data.get"):
+            return self.queue.dequeue()
 
     def __iter__(self) -> Iterator[Any]:
         self.start()

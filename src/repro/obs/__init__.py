@@ -1,7 +1,8 @@
 """Observability: distributed EEG spans + unified metrics (DESIGN.md §16).
 
 - :mod:`repro.obs.spans` — cheap start/end span events from the real
-  execution paths (fused executors, wire RPCs, rendezvous waits).
+  execution paths (fused executors, wire RPCs, rendezvous waits), and
+  ``span()``, a JAX profiler span for the jitted serving and data paths.
 - :mod:`repro.obs.metrics` — the process-global registry of named
   counters/gauges/histograms (absorbs the legacy ``STATS`` dicts).
 - :mod:`repro.obs.export` — merges per-process streams into one

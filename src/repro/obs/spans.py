@@ -1,4 +1,17 @@
-"""Span recording for the distributed EEG (DESIGN.md §16).
+"""Span recording: the distributed EEG's buffers and the profiler's spans
+(DESIGN.md §16).
+
+Two kinds of span, for two readers:
+
+- :class:`SpanRecorder` events are the EEG's, from the graph engine, the
+  wire layer and the RPC client, merged across processes into one
+  Chrome trace (``obs/export.py``).
+- :func:`span` is for the jitted paths (the continuous batcher, the
+  input prefetcher) run under the JAX profiler: a
+  ``jax.profiler.TraceAnnotation``, written by the profiler on the clock
+  of its device planes, so that a gap on the device can be read against
+  what the host was doing.  The profiler being on is its only switch;
+  off, entering and leaving one costs about a microsecond.
 
 A :class:`SpanRecorder` is a thread-safe append-only buffer of start/end
 events.  Executors, the wire layer and the RPC client each record into
@@ -6,7 +19,7 @@ one when tracing is enabled; when it is not, every instrumentation site
 reduces to a single ``is None`` check — the off path allocates nothing
 and takes no locks (asserted by benchmark b15).
 
-Timestamps are ``time.time()`` (epoch seconds) rather than a process
+Its timestamps are ``time.time()`` (epoch seconds) rather than a process
 monotonic clock: merging streams from several processes then reduces to
 subtracting one estimated clock offset per stream (§16.3), instead of
 reconstructing per-process epochs.  Durations stay meaningful because a
@@ -30,6 +43,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 CAT_OP = "op"
 CAT_REGION = "region"
@@ -112,3 +127,14 @@ def install(recorder: Optional[SpanRecorder]) -> Optional[SpanRecorder]:
     global _GLOBAL
     _GLOBAL = recorder
     return recorder
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans.  Names are ``<layer>.<phase>``: ``serve.*`` in the
+# continuous batcher, ``data.*`` in the prefetcher.
+
+
+def span(name: str) -> TraceAnnotation:
+    """A host span named ``name`` in the JAX profiler's trace, when one is
+    being recorded; use as ``with span("serve.step"): ...``."""
+    return TraceAnnotation(name)

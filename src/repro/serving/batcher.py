@@ -28,6 +28,7 @@ import numpy as np
 from ..models.api import Model
 from ..models.params import init_params
 from ..obs import metrics as obs_metrics
+from ..obs.spans import span
 from ..runtime.queues import FIFOQueue, QueueClosed
 
 
@@ -65,7 +66,7 @@ class RequestResult:
     tokens: List[int]
     prompt_len: int
     steps: int
-    latency_s: float
+    latency_s: float  # submit() to the last token, queueing included
 
 
 class ContinuousBatcher:
@@ -94,13 +95,15 @@ class ContinuousBatcher:
         self.slot_pos = np.zeros(n_slots, dtype=np.int64)
         self.slot_pending: List[List[int]] = [[] for _ in range(n_slots)]
         self.slot_out: List[List[int]] = [[] for _ in range(n_slots)]
-        self.slot_t0 = np.zeros(n_slots)
+        # time.perf_counter() at submit(): a request's latency counts its
+        # wait in the queue
+        self.slot_submitted = np.zeros(n_slots)
         self.slot_steps = np.zeros(n_slots, dtype=np.int64)
         self.stats = {"steps": 0, "slot_tokens": 0, "idle_slot_tokens": 0}
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
-        self.queue.enqueue(req)
+        self.queue.enqueue((time.perf_counter(), req))
 
     def _reset_slot_cache(self, s: int) -> None:
         self.cache = jax.tree.map(
@@ -114,14 +117,14 @@ class ContinuousBatcher:
             if self.queue.size() == 0:
                 continue
             try:
-                req = self.queue.dequeue()
+                submitted, req = self.queue.dequeue()
             except (TimeoutError, QueueClosed):
                 return
             self.slot_req[s] = req
             self.slot_pos[s] = 0
             self.slot_pending[s] = list(req.prompt)
             self.slot_out[s] = []
-            self.slot_t0[s] = time.time()
+            self.slot_submitted[s] = submitted
             self.slot_steps[s] = 0
             self._reset_slot_cache(s)
 
@@ -130,30 +133,46 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------------
     def step(self) -> int:
-        """Advance every live slot one token; returns #completed requests."""
-        self._try_fill_slots()
-        live = self._live()
-        if not live:
-            return 0
+        """Advance every live slot one token; returns #completed requests.
 
-        tokens = np.zeros((self.n_slots, 1), dtype=np.int32)
-        for s in live:
-            if self.slot_pending[s]:
-                tokens[s, 0] = self.slot_pending[s][0]
-            elif self.slot_out[s]:
-                tokens[s, 0] = self.slot_out[s][-1]
-            else:
-                tokens[s, 0] = 0
-        positions = jnp.asarray(self.slot_pos.astype(np.int32))
+        Each phase is a profiler span (``obs.spans.span``) inside
+        ``serve.step``: ``serve.admit``, ``serve.dispatch``,
+        ``serve.device_wait`` (the host waits for the step),
+        ``serve.logits_to_host`` (the copy alone) and ``serve.sample``."""
+        with span("serve.step"):
+            with span("serve.admit"):
+                self._try_fill_slots()
+            live = self._live()
+            if not live:
+                return 0
 
-        logits, self.cache = self._step(self.params, self.cache,
-                                        jnp.asarray(tokens), positions)
-        self.stats["steps"] += 1
-        self.stats["slot_tokens"] += len(live)
-        self.stats["idle_slot_tokens"] += self.n_slots - len(live)
+            with span("serve.dispatch"):
+                tokens = np.zeros((self.n_slots, 1), dtype=np.int32)
+                for s in live:
+                    if self.slot_pending[s]:
+                        tokens[s, 0] = self.slot_pending[s][0]
+                    elif self.slot_out[s]:
+                        tokens[s, 0] = self.slot_out[s][-1]
+                    else:
+                        tokens[s, 0] = 0
+                positions = jnp.asarray(self.slot_pos.astype(np.int32))
+                logits, self.cache = self._step(self.params, self.cache,
+                                                jnp.asarray(tokens), positions)
+                last = logits[:, 0, :]
+                self.stats["steps"] += 1
+                self.stats["slot_tokens"] += len(live)
+                self.stats["idle_slot_tokens"] += self.n_slots - len(live)
+            with span("serve.device_wait"):
+                jax.block_until_ready(last)
+            with span("serve.logits_to_host"):
+                logits_np = np.asarray(last)
+            with span("serve.sample"):
+                return self._sample(live, logits_np)
 
+    def _sample(self, live: List[int], logits_np: np.ndarray) -> int:
+        """Take each live slot's next token from the step's logits, and
+        finish the requests that are done; returns how many finished."""
         done = 0
-        logits_np = np.asarray(logits[:, 0, :])
         for s in live:
             req = self.slot_req[s]
             self.slot_pos[s] += 1
@@ -175,7 +194,7 @@ class ContinuousBatcher:
                         or (req.eos_id is not None and tok == req.eos_id)
                         or self.slot_pos[s] >= self.max_seq - 1)
             if finished:
-                latency = time.time() - self.slot_t0[s]
+                latency = time.perf_counter() - self.slot_submitted[s]
                 self.results[req.rid] = RequestResult(
                     rid=req.rid, tokens=list(self.slot_out[s]),
                     prompt_len=len(req.prompt),

@@ -1,7 +1,9 @@
 """§4.5/§4.6 input pipeline: readers, prefetch queues, determinism."""
+import glob
 import os
 import time
 
+import jax
 import numpy as np
 
 from repro.data import (SyntheticLMDataset, FileRecordReader, Prefetcher,
@@ -73,3 +75,23 @@ def test_input_pipeline_end_to_end():
     b2 = pipe.get()
     assert not np.array_equal(b["tokens"], b2["tokens"])
     pipe.stop()
+
+
+def test_prefetcher_get_is_a_profiler_span(tmp_path):
+    from jax.profiler import ProfileData
+
+    pf = Prefetcher(iter(range(5)), capacity=2).start()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        got = [pf.get() for _ in range(3)]
+    finally:
+        jax.profiler.stop_trace()
+    assert got == [0, 1, 2]
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    names = [ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events]
+    assert names.count("data.get") == 3
+

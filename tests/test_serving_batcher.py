@@ -1,4 +1,9 @@
-"""Continuous-batching serving layer: correctness vs sequential decode."""
+"""Continuous-batching serving layer: correctness vs sequential decode,
+request latency, and the step's profiler spans."""
+import glob
+import os
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +12,7 @@ import pytest
 from repro.configs import get_config
 from repro.models.api import Model
 from repro.models.params import init_params
+from repro.obs import metrics as obs_metrics
 from repro.serving import ContinuousBatcher, Request
 
 
@@ -76,3 +82,78 @@ def test_eos_terminates_early(setup):
                            eos_id=first))
     results = batcher.run_until_drained()
     assert results[0].tokens == [first]
+
+
+def test_latency_counts_the_wait_in_the_queue(setup):
+    cfg, model, params = setup
+    batcher = ContinuousBatcher(model, params, n_slots=1, max_seq=64)
+    hist = obs_metrics.histogram("serving.request_latency_s")
+    count0, sum0 = hist.count, hist.sum
+    t_submit = time.perf_counter()
+    batcher.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=4))
+    batcher.submit(Request(rid=1, prompt=[3, 4], max_new_tokens=2))
+    time.sleep(0.2)  # both wait in the queue; then rid 1 waits behind rid 0
+    t_admit = None
+    while batcher.queue.size() or batcher._live():
+        t = time.perf_counter()
+        batcher.step()
+        req = batcher.slot_req[0]
+        if t_admit is None and req is not None and req.rid == 1:
+            t_admit = t
+    t_end = time.perf_counter()
+    res = batcher.results
+    assert t_admit is not None and res[0].steps > 0
+    # from submit(), not from admission into the slot
+    assert t_admit - t_submit <= res[1].latency_s <= t_end - t_submit
+    assert t_admit - t_submit >= 0.2
+    assert res[0].latency_s >= 0.2
+    assert hist.count == count0 + 2
+    assert hist.sum - sum0 == pytest.approx(
+        res[0].latency_s + res[1].latency_s)
+
+
+SERVE_SPANS = ("serve.admit", "serve.dispatch", "serve.device_wait",
+               "serve.logits_to_host", "serve.sample")
+
+
+def test_step_phases_are_profiler_spans(setup, tmp_path):
+    """Each phase of a step is a profiler span nested in ``serve.step``, in
+    the order it runs, once per step."""
+    from jax.profiler import ProfileData
+
+    cfg, model, params = setup
+    batcher = ContinuousBatcher(model, params, n_slots=2, max_seq=64)
+    batcher.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=2))
+    batcher.run_until_drained()  # compiles outside the trace
+    batcher.submit(Request(rid=1, prompt=[1, 2, 3], max_new_tokens=3))
+    batcher.submit(Request(rid=2, prompt=[4], max_new_tokens=2))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        steps = 0
+        while batcher.queue.size() or batcher._live():
+            batcher.step()
+            steps += 1
+    finally:
+        jax.profiler.stop_trace()
+    assert steps == 5 and len(batcher.results) == 3
+
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = sorted((ev.start_ns, -ev.duration_ns, ev.name,
+                     ev.start_ns + ev.duration_ns)
+                    for plane in ProfileData.from_file(path).planes
+                    if plane.name.startswith("/host:")
+                    for line in plane.lines for ev in line.events
+                    if ev.name.startswith("serve."))
+    outer = [(s, e) for s, _, n, e in events if n == "serve.step"]
+    assert len(outer) == steps
+    for s0, e0 in outer:
+        inner = [(n, s, e) for s, _, n, e in events
+                 if n != "serve.step" and s0 <= s and e <= e0]
+        assert [n for n, _, _ in inner] == list(SERVE_SPANS)
+        # one after another, without overlap
+        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    assert len(events) == steps * (1 + len(SERVE_SPANS))
+
