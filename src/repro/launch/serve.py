@@ -88,8 +88,9 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = False, batch: int = 4,
             return logits, c
     else:
         # params go in as an argument: closed over, they would be baked
-        # into the executable as constants (2.5 GB for qwen2-0.5b)
-        decode = jax.jit(model.serve_step)
+        # into the executable as constants (2.5 GB for qwen2-0.5b); the
+        # cache is donated, so each token's k/v are written in place
+        decode = jax.jit(model.serve_step, donate_argnums=(1,))
 
         def step(c, tk, t):
             return decode(params, c, tk, t)

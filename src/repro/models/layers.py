@@ -67,7 +67,7 @@ def gated_mlp(x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array,
 
 
 def _mask_bias(pos_q: jax.Array, pos_kv: jax.Array, causal: bool,
-               window: int, kv_len_valid: Optional[jax.Array]) -> jax.Array:
+               window: int) -> jax.Array:
     """(Sq, Skv) additive bias in f32: 0 allowed, -inf masked."""
     ok = pos_kv[None, :] >= 0  # ring-buffer slots not yet written sit at p<0
     ok = jnp.broadcast_to(ok, (pos_q.shape[0], pos_kv.shape[0]))
@@ -75,8 +75,6 @@ def _mask_bias(pos_q: jax.Array, pos_kv: jax.Array, causal: bool,
         ok &= pos_kv[None, :] <= pos_q[:, None]
     if window > 0:
         ok &= pos_kv[None, :] > (pos_q[:, None] - window)
-    if kv_len_valid is not None:
-        ok &= pos_kv[None, :] < kv_len_valid
     return jnp.where(ok, 0.0, -jnp.inf).astype(jnp.float32)
 
 
@@ -103,7 +101,6 @@ def attention(
     causal: bool = True,
     window: int = 0,
     q_chunk: int = 0,
-    kv_len_valid: Optional[jax.Array] = None,
     head_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Padded-GQA attention.
@@ -119,7 +116,7 @@ def attention(
     if q_chunk <= 0 or Sq <= q_chunk or Sq % q_chunk != 0:
         # indivisible sequences (e.g. whisper's 1500 encoder frames) take
         # the one-shot path; chunking is a memory optimisation only
-        bias = _mask_bias(pos_q, pos_kv, causal, window, kv_len_valid)
+        bias = _mask_bias(pos_q, pos_kv, causal, window)
         return _attn_block(q, k, v, bias, head_mask)
     n_chunks = Sq // q_chunk
 
@@ -138,7 +135,7 @@ def attention(
             vs = jax.lax.dynamic_slice_in_dim(vp, i * q_chunk, window + q_chunk, axis=1)
             pq = jax.lax.dynamic_slice_in_dim(pos_q, i * q_chunk, q_chunk)
             pk = jax.lax.dynamic_slice_in_dim(pos_kv_p, i * q_chunk, window + q_chunk)
-            bias = _mask_bias(pq, pk, causal, window, kv_len_valid)
+            bias = _mask_bias(pq, pk, causal, window)
             return _attn_block(qs, ks, vs, bias, head_mask)
 
         _, outs = jax.lax.scan(lambda c, i: (c, chunk_body(i)), None,
@@ -150,12 +147,45 @@ def attention(
     def chunk_body(i):
         qs = jax.lax.dynamic_slice_in_dim(q, i * q_chunk, q_chunk, axis=1)
         pq = jax.lax.dynamic_slice_in_dim(pos_q, i * q_chunk, q_chunk)
-        bias = _mask_bias(pq, pos_kv, causal, window, kv_len_valid)
+        bias = _mask_bias(pq, pos_kv, causal, window)
         return _attn_block(qs, k, v, bias, head_mask)
 
     _, outs = jax.lax.scan(lambda c, i: (c, chunk_body(i)), None,
                            jnp.arange(n_chunks))
     return jnp.moveaxis(outs, 0, 1).reshape(B, Sq, KV, G, Dh)
+
+
+def decode_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    k_new: jax.Array, v_new: jax.Array,
+    *,
+    pos_q: jax.Array, pos_kv: jax.Array,
+    window: int = 0,
+    head_mask: Optional[jax.Array] = None,
+) -> jax.Array:
+    """One query token against a read-only cache and against itself.
+
+    q: (B, 1, KVp, G, Dh); k, v: (B, T, KVp, Dh), the cache, masked by
+    pos_kv as ``attention`` masks it (causal); k_new, v_new: (B, 1, KVp,
+    Dh), the token's own, always attended.  The cache's scores and the
+    token's own share one softmax, so the cache is never concatenated."""
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    bias = _mask_bias(pos_q, pos_kv, True, window)
+    s = jnp.einsum("bskgd,btkd->bsktg", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = s + bias[None, :, None, :, None]
+    s_self = jnp.einsum("bskgd,bskd->bskg", q, k_new,
+                        preferred_element_type=jnp.float32) * scale
+    m = jnp.maximum(jnp.max(s, axis=3), s_self)  # finite: the self-term
+    p = jnp.exp(s - m[:, :, :, None, :])
+    p_self = jnp.exp(s_self - m)
+    denom = jnp.sum(p, axis=3) + p_self
+    o = (jnp.einsum("bsktg,btkd->bskgd", p.astype(v.dtype), v)
+         + p_self[..., None].astype(v.dtype) * v_new[:, :, :, None, :])
+    o = o / denom[..., None].astype(o.dtype)
+    if head_mask is not None:
+        o = o * head_mask
+    return o
 
 
 def duplicate_kv(kv: jax.Array, plan: PadPlan) -> jax.Array:

@@ -522,9 +522,18 @@ def init_cache_desc(cfg: ModelConfig, plan: PadPlan, *, batch: int,
     return desc
 
 
-def _decode_attn(cfg, plan, p, x, kcache, vcache, pos, window):
-    """One-token attention against a (possibly ring-buffer) cache.
-    kcache/vcache: (B, span, KVp, hd).  Returns (out, new_k, new_v)."""
+def _write_index(pos, span: int, window: int):
+    """The cache entry a token at ``pos`` is written to: a ring buffer's
+    ``pos % span`` for a window, else ``pos`` (the last entry once full)."""
+    return jnp.mod(pos, span) if window else jnp.minimum(pos, span - 1)
+
+
+def _decode_attn_read(cfg, plan, p, x, kcache, vcache, pos, window):
+    """One-token attention against a read-only (possibly ring-buffer)
+    cache.  kcache/vcache: (B, span, KVp, hd).  The token attends to itself
+    through its own k/v; the entry they will overwrite (``_write_index``)
+    is masked, so nothing reads back an entry not written yet.  Returns
+    (out, k, v), k/v (B, 1, KVp, hd) for the caller to write."""
     B = x.shape[0]
     span = kcache.shape[1]
     positions = jnp.full((1,), pos, dtype=jnp.int32)
@@ -533,23 +542,29 @@ def _decode_attn(cfg, plan, p, x, kcache, vcache, pos, window):
     q = q.reshape(B, 1, plan.kv_pad, plan.group, cfg.hd)
     k = L.duplicate_kv(k, plan)
     v = L.duplicate_kv(v, plan)
-    write_at = jnp.mod(pos, span) if window else jnp.minimum(pos, span - 1)
-    kcache = jax.lax.dynamic_update_slice_in_dim(kcache, k, write_at, axis=1)
-    vcache = jax.lax.dynamic_update_slice_in_dim(vcache, v, write_at, axis=1)
+    idx = jnp.arange(span, dtype=jnp.int32)
     if window:
         # ring buffer: slot s holds absolute position p iff p % span == s
         base = (pos // span) * span
-        idx = jnp.arange(span, dtype=jnp.int32)
         pos_kv = jnp.where(idx <= jnp.mod(pos, span), base + idx,
                            base - span + idx)
     else:
-        pos_kv = jnp.arange(span, dtype=jnp.int32)
+        pos_kv = idx
+    pos_kv = jnp.where(idx == _write_index(pos, span, window), -1, pos_kv)
     hm = jnp.asarray(plan.head_mask(), x.dtype).reshape(plan.kv_pad, plan.group, 1)
-    attn = L.attention(q, kcache, vcache, pos_q=positions, pos_kv=pos_kv,
-                       causal=True, window=window, head_mask=hm,
-                       kv_len_valid=None)
+    attn = L.decode_attention(q, kcache, vcache, k, v, pos_q=positions,
+                              pos_kv=pos_kv, window=window, head_mask=hm)
     out = _attn_out(cfg, plan, p, attn, B, 1)
-    return out, kcache, vcache
+    return out, k, v
+
+
+def _decode_attn(cfg, plan, p, x, kcache, vcache, pos, window):
+    """``_decode_attn_read`` with the token's k/v written into the caches:
+    returns (out, new_kcache, new_vcache)."""
+    out, k, v = _decode_attn_read(cfg, plan, p, x, kcache, vcache, pos, window)
+    at = _write_index(pos, kcache.shape[1], window)
+    return (out, jax.lax.dynamic_update_slice_in_dim(kcache, k, at, axis=1),
+            jax.lax.dynamic_update_slice_in_dim(vcache, v, at, axis=1))
 
 
 def serve_step(cfg: ModelConfig, plan: PadPlan, params,
@@ -557,8 +572,13 @@ def serve_step(cfg: ModelConfig, plan: PadPlan, params,
                *, compute_dtype: Any = jnp.float32,
                serve_longctx: bool = False, n_token_groups: int = 1,
                scan_unroll: int = 1) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One decode step: tokens (B,1) + cache @ pos -> (logits (B,1,V), cache)."""
-    B = tokens.shape[0]
+    """One decode step: tokens (B,1) + cache @ pos -> (logits (B,1,V), cache).
+
+    The attention cache is read-only inside the layer loop (layer ``i``
+    indexed, not passed as scan xs/ys): each layer's new k/v leave the
+    loop as small ys and are written once per leaf after it, so a donated
+    cache is updated in place, not copied or relaid out.  SSM state and
+    conv caches, small and rewritten whole each step, ride the scan."""
     groups = block_groups(cfg, serve_longctx=serve_longctx)
     x = jnp.take(params["embed"].astype(compute_dtype), tokens, axis=0)
     new_cache: Dict[str, Any] = {}
@@ -567,37 +587,41 @@ def serve_step(cfg: ModelConfig, plan: PadPlan, params,
         gp = params[f"g{gi}"]
         gc = cache[f"g{gi}"]
 
-        def layer_fn(x, packed, g=g):
-            pl, cc = packed
-            ncc = {}
+        def layer_fn(x, packed, g=g, gc=gc):
+            pl, i, ssm_c = packed
             if g.kind == "ssm":
                 h = L.rmsnorm(x, pl["ln"], cfg.norm_eps)
-                y, ssm_cache = ssm_mixer(cfg, plan, pl, h, cache=cc["ssm"])
-                ncc["ssm"] = ssm_cache
-                return x + y, ncc
+                y, ssm_cache = ssm_mixer(cfg, plan, pl, h, cache=ssm_c)
+                return x + y, (None, None, ssm_cache)
             h = L.rmsnorm(x, pl["ln1"], cfg.norm_eps)
-            a_out, nk, nv = _decode_attn(cfg, plan, pl, x, cc["k"], cc["v"],
-                                         pos, g.window)
-            ncc["k"], ncc["v"] = nk, nv
+            kc = jax.lax.dynamic_index_in_dim(gc["k"], i, keepdims=False)
+            vc = jax.lax.dynamic_index_in_dim(gc["v"], i, keepdims=False)
+            a_out, k, v = _decode_attn_read(cfg, plan, pl, x, kc, vc, pos,
+                                            g.window)
             if g.kind in ("hybrid", "hybrid_swa"):
                 s_out, ssm_cache = ssm_mixer(cfg, plan, pl["ssm"], h,
-                                             cache=cc["ssm"])
-                ncc["ssm"] = ssm_cache
+                                             cache=ssm_c)
                 fused = 0.5 * (L.rmsnorm(a_out, pl["attn_fuse_norm"], cfg.norm_eps)
                                + L.rmsnorm(s_out, pl["ssm_fuse_norm"], cfg.norm_eps))
                 x = x + fused
-                return mlp_block(cfg, pl, x), ncc
+                return mlp_block(cfg, pl, x), (k, v, ssm_cache)
             x = x + a_out
             if g.kind == "moe":
                 x, _ = moe_block(cfg, plan, pl, x, n_token_groups)
-                return x, ncc
-            return mlp_block(cfg, pl, x), ncc
+                return x, (k, v, None)
+            return mlp_block(cfg, pl, x), (k, v, None)
 
-        def scan_fn(x, packed):
-            return layer_fn(x, packed)
-
-        x, ncache = jax.lax.scan(scan_fn, x, (gp, gc), unroll=scan_unroll)
-        new_cache[f"g{gi}"] = ncache
+        x, (ks, vs, ssm_cache) = jax.lax.scan(
+            layer_fn, x, (gp, jnp.arange(g.count), gc.get("ssm")),
+            unroll=scan_unroll)
+        ngc: Dict[str, Any] = {}
+        if "k" in gc:
+            at = _write_index(pos, gc["k"].shape[2], g.window)
+            ngc["k"] = jax.lax.dynamic_update_slice_in_dim(gc["k"], ks, at, axis=2)
+            ngc["v"] = jax.lax.dynamic_update_slice_in_dim(gc["v"], vs, at, axis=2)
+        if ssm_cache is not None:
+            ngc["ssm"] = ssm_cache
+        new_cache[f"g{gi}"] = ngc
 
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_from_hidden(cfg, plan, params, x)

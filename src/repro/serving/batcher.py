@@ -16,6 +16,7 @@ temperature.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -37,18 +38,37 @@ def _slot_step_for(model: Model):
     Executable cache, DESIGN.md §5): restarting or multiplying batchers
     over one model reuses the traced/jitted vmapped slot step instead of
     re-tracing it.  The step is cached on the model instance itself so
-    its lifetime tracks the model — nothing is pinned process-wide."""
+    its lifetime tracks the model — nothing is pinned process-wide.
+
+    The cache argument is donated: the step writes each slot's new k/v
+    into it in place, so the caller must use the returned cache.  The
+    layer loop is unrolled: in a loop the cache and the weights are
+    loop-invariant operands of default-precision matmuls, and the TPU
+    compiler converts each whole one to bfloat16 ahead of the loop on
+    every step; unrolled, each layer's slice is converted inside its
+    matmul."""
     step = getattr(model, "_batcher_slot_step", None)
     if step is not None:
         return step
 
     def one_slot_step(params, cache, token, pos):
-        logits, new_cache = model.serve_step(params, cache, token[None, :], pos)
+        logits, new_cache = model.serve_step(params, cache, token[None, :],
+                                             pos, scan_unroll=True)
         return logits[0], new_cache
 
-    step = jax.jit(jax.vmap(one_slot_step, in_axes=(None, 0, 0, 0)))
+    step = jax.jit(jax.vmap(one_slot_step, in_axes=(None, 0, 0, 0)),
+                   donate_argnums=(1,))
     model._batcher_slot_step = step
     return step
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _reset_slot(cache, empty, s):
+    """Slot ``s`` of the slot-stacked ``cache`` set to ``empty``, in place:
+    one executable for every slot, touching that slot's entries alone."""
+    return jax.tree.map(
+        lambda full, e: jax.lax.dynamic_update_index_in_dim(full, e, s, 0),
+        cache, empty)
 
 
 @dataclasses.dataclass
@@ -106,9 +126,7 @@ class ContinuousBatcher:
         self.queue.enqueue((time.perf_counter(), req))
 
     def _reset_slot_cache(self, s: int) -> None:
-        self.cache = jax.tree.map(
-            lambda full, empty: full.at[s].set(empty),
-            self.cache, self._empty_cache)
+        self.cache = _reset_slot(self.cache, self._empty_cache, np.int32(s))
 
     def _try_fill_slots(self) -> None:
         for s in range(self.n_slots):

@@ -11,6 +11,7 @@ module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +125,69 @@ def test_qwen2_decode_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def _qwen2_slot_cache(one_chip, n_slots=64, max_seq=2048):
+    """qwen2-0.5b's model, parameters and slot-leading cache as the batcher
+    holds it, ``(n_slots,) + init_cache_desc(batch=1)``, as shapes."""
+    from repro.configs import get_config
+    from repro.models.api import Model
+    from repro.models.params import abstract_params
+
+    model = Model.for_config(get_config("qwen2-0.5b"))
+    one = abstract_params(model.init_cache_desc(batch=1, max_seq=max_seq))
+    cache = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((n_slots,) + s.shape, s.dtype), one)
+    return (model, _on(one_chip, model.abstract_params()),
+            _on(one_chip, cache), _on(one_chip, one))
+
+
+def _hlo_ops(text):
+    """(opcode, element type, dims) of each HLO instruction."""
+    pat = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+    return [(m.group(3), m.group(1), m.group(2))
+            for m in map(pat.match, text.splitlines()) if m]
+
+
+def test_qwen2_slot_step_updates_the_cache_in_place_on_v5e(one_chip):
+    """The batcher's slot step at 64 slots x 2048 aliases the donated
+    cache and neither copies, converts, transposes nor restacks it: no
+    copy, convert, transpose or fusion yields a whole-cache or
+    whole-layer shape, in any element type."""
+    from repro.serving.batcher import _slot_step_for
+
+    n, span = 64, 2048
+    model, params, cache, _ = _qwen2_slot_cache(one_chip, n, span)
+    compiled = _slot_step_for(model).lower(
+        params, cache, _on(one_chip, jax.ShapeDtypeStruct((n, 1), jnp.int32)),
+        _on(one_chip, jax.ShapeDtypeStruct((n,), jnp.int32))).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes <= 2.5e9, mem.temp_size_in_bytes
+    layers, kv, hd = 24, 2, 64
+    whole = {f"{n},{layers},1,{span},{kv},{hd}",
+             f"{layers},{n},1,{span},{kv},{hd}",
+             f"{n},1,{span},{kv},{hd}",
+             f"1,{n},1,{span},{kv},{hd}"}
+    moved = [(op, ty, dims) for op, ty, dims in _hlo_ops(compiled.as_text())
+             if dims in whole and op in ("copy", "copy-start", "convert",
+                                         "transpose", "fusion")]
+    assert not moved, moved
+
+
+def test_qwen2_slot_reset_updates_one_slot_in_place_on_v5e(one_chip):
+    """The batcher's slot reset, one executable for every slot, aliases
+    the donated cache and copies no array."""
+    from repro.serving.batcher import _reset_slot
+
+    _, _, cache, empty = _qwen2_slot_cache(one_chip)
+    compiled = _reset_slot.lower(
+        cache, empty,
+        _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    copies = [(op, ty, dims) for op, ty, dims in _hlo_ops(compiled.as_text())
+              if op in ("copy", "copy-start") and ty == "f32"]
+    assert not copies, copies
