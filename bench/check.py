@@ -1,6 +1,7 @@
 """What decides ``correct``: the system's results against the plain
-reference (``bench/reference``), each number beside its cell's limit
-(``bench/limits/<cell>.json``).
+reference of the configuration's architecture (``bench/arch``; the
+references live in ``bench/reference``), each number beside its cell's
+limit (``bench/limits/<cell>.json``).
 
 Training.  The window's own step, on the run's first three batches,
 against the reference's three AdamW steps from the same weights:
@@ -31,9 +32,9 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from . import gen
+from . import arch, gen
 from . import weights as W
-from .reference import decoder as ref
+from .reference.optim import adamw
 
 GRAD_FLOOR = 1e-3   # of the median leaf's reference gradient norm
 
@@ -103,12 +104,13 @@ def train_reference(c: Dict[str, Any], t: Dict[str, Any], seed: int,
     import jax.numpy as jnp
 
     o = c["deployment"]["optimizer"]
+    ref = arch.of(c).reference
     batches = gen.train_batches(t, c["vocab_size"], c["eos_token_id"], seed)
     with jax.default_matmul_precision("highest"):
         grad_row = jax.jit(jax.value_and_grad(
             lambda w, x, y: ref.row_loss(c, w, x, y, int8)))
         add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
-        update = jax.jit(lambda p, g, m, v, s: ref.adamw(p, g, m, v, s, o))
+        update = jax.jit(lambda p, g, m, v, s: adamw(p, g, m, v, s, o))
         p0 = W.make(c, W.key_for(seed, 0))
         params = p0
         m = jax.tree.map(jnp.zeros_like, p0)
@@ -173,6 +175,8 @@ def _served_gaps(c: Dict[str, Any], control: str):
     "int8": of the token that a reference in that precision puts first)."""
     import jax
     import jax.numpy as jnp
+
+    ref = arch.of(c).reference
 
     def fn(w, tokens, pos, served, mask):
         lg = ref.logits(c, w, tokens)

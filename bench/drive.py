@@ -339,8 +339,9 @@ def open_loop(cell, seed: int, seconds: float, trace: bool, t_start: float,
     backlog = len([r for r in reqs[:i] if r.rid not in sv.last]) + sum(
         1 for r in reqs[i:] if r.due <= seconds)
     positions = sv.positions
-    # drain: the window's requests finish under the same offered load
-    deadline = sv.t0 + seconds + drain_s
+    # drain: the window's requests finish under the same offered load; its
+    # time starts once the window, and with it the trace, has stopped
+    deadline = now() + drain_s
     while any(r not in sv.last for r in counted) and now() < deadline:
         offer(now() - sv.t0)
         if sv.busy():

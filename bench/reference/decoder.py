@@ -5,7 +5,7 @@ grouped-query attention (RMSNorm, rotary position embedding on half-split
 head dimensions, causal softmax attention with query head i reading kv head
 i // (heads / kv_heads), optional q/k/v biases, SwiGLU MLP with ``w3`` as
 the gate and ``w1`` as the up projection, tied or separate output
-projection) over the weight layout of ``bench/weights.py``.  It imports
+projection) over the weight layout of ``bench/arch/dense_gqa.py``.  It imports
 nothing of the system under test.  Callers run it under
 ``jax.default_matmul_precision("highest")`` for float32, and in float32
 throughout; ``dtype`` lowers the precision for the controls, and ``int8``
@@ -24,6 +24,8 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+
+from .optim import adamw  # noqa: F401  (the training reference's optimizer)
 
 
 def _rms(x, w, eps):
@@ -109,21 +111,3 @@ def row_loss(c: Dict[str, Any], w: Dict[str, Any], tokens, labels,
     lg = logits(c, w, tokens, int8=int8)
     gold = jnp.take_along_axis(lg, labels[:, None], axis=-1)[:, 0]
     return jnp.mean(jax.nn.logsumexp(lg, axis=-1) - gold)
-
-
-def adamw(params, grads, m, v, step: int, o: Dict[str, float]):
-    """One AdamW step as the configuration states it: global-norm clip,
-    bias-corrected moments, decoupled weight decay.  Returns the clipped
-    gradient too."""
-    leaves = jax.tree.leaves(grads)
-    gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in leaves) + 1e-12)
-    scale = jnp.minimum(1.0, o["grad_clip"] / gnorm)
-    g = jax.tree.map(lambda x: x * scale, grads)
-    m = jax.tree.map(lambda a, b: o["b1"] * a + (1 - o["b1"]) * b, m, g)
-    v = jax.tree.map(lambda a, b: o["b2"] * a + (1 - o["b2"]) * b * b, v, g)
-    bc1, bc2 = 1 - o["b1"] ** step, 1 - o["b2"] ** step
-    params = jax.tree.map(
-        lambda p, a, b: p - o["lr"] * ((a / bc1) / (jnp.sqrt(b / bc2) + o["eps"])
-                                       + o["weight_decay"] * p),
-        params, m, v)
-    return params, m, v, g

@@ -11,6 +11,7 @@ import os
 import sys
 from typing import Any, Dict, Tuple
 
+from . import arch
 from .spec import CHECKOUT
 
 SRC = os.path.join(CHECKOUT, "src")
@@ -49,19 +50,9 @@ def enable_compile_cache(path: str = "") -> str:
 
 
 def model_config(c: Dict[str, Any]):
-    from repro.models.config import ModelConfig
-
-    H = c["num_attention_heads"]
-    return ModelConfig(
-        arch_id=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=H,
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"],
-        head_dim=c.get("head_dim") or c["hidden_size"] // H,
-        qkv_bias=bool(c.get("attention_bias")),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        act=c["hidden_act"], source=c["source"])
+    """The program's ``ModelConfig`` for configuration ``c``, as its
+    architecture's module builds it."""
+    return arch.of(c).program_config(c)
 
 
 def check_layout(model, weights) -> None:

@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Record the trace ``test_host_spans.py`` reads, on a chip.
+"""Record the trace ``test_trace_reduce.py`` reads beside ``small.xplane.pb``,
+on a chip.
 
   python3 bench/tests/record_spans_trace.py <out_dir>
 
@@ -11,7 +12,7 @@ the batcher's own ``serve.*`` spans: a real step on the device, a real
 copy of its logits to the host, and a 20 ms host-only sleep put in front
 of the sampling, under ``serve.sample``.  Copies the ``.xplane.pb`` to
 ``<out_dir>/spans.xplane.pb`` and prints every plane and line with its
-event count, and both reductions of it.
+event count, and its reduction.
 """
 from __future__ import annotations
 
@@ -35,7 +36,7 @@ def main(out_dir: str) -> int:
     import jax
     from jax.profiler import ProfileData
 
-    from bench import host_spans, trace_reduce
+    from bench import trace_reduce
     from repro.configs import get_config
     from repro.models.api import Model
     from repro.serving import ContinuousBatcher, Request
@@ -82,7 +83,6 @@ def main(out_dir: str) -> int:
                       f"{sorted({e.name for e in evs})[:6]}")
     print(f"{os.path.getsize(path)} bytes")
     print(trace_reduce.reduce(path))
-    print(host_spans.reduce(path))
     return 0
 
 

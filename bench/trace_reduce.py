@@ -1,18 +1,32 @@
-"""From a profiler trace (``.xplane.pb``) to device busy time, the top
-device operations, and idle gaps labelled by what the host was doing.
+"""From a profiler trace (``.xplane.pb``) to device busy time, every
+device operation's time, the host's spans, and idle gaps split by what
+the host was doing; one pass over the file.
 
 - The window is the host span named ``bench.window`` that the harness
   writes around the measured loop.
+- Host spans are the host events named ``<area>.<phase>``: lower-case
+  words joined by dots (``bench.*`` from the harness, ``serve.*`` and
+  ``data.*`` from the program's ``repro.obs.spans.span``, and any that
+  a later change adds).  The runtime's own events (``PjitFunction(...)``,
+  ``tpu::System::...``) have no such name.
 - Busy time on a chip is the union of the intervals of the events on its
   plane's ``XLA Ops`` line (planes named ``/device:TPU:<n>``), clipped to
   the window, averaged over the chips that ran anything.
-- A device op's time is its duration inside the window.  Ops that hold
-  other ops (``while``, ``conditional``, ``call``) are not listed: the
-  ops of their bodies are, on the same line.  Ops are named by their HLO
-  name, kind and result type.
+- A device op's time is its own time inside the window: an op that holds
+  other ops (``while``, ``conditional``, ``call``), whose body's ops lie
+  on the same line inside it, keeps only what they leave uncovered.  Ops
+  are named by their HLO name, kind and result type.
 - An idle gap is a stretch of the window in which no operation runs on
-  the first chip.  It is labelled by the innermost ``bench.*`` host span
-  (other than the window) that covers its midpoint, or ``host:other``.
+  the first chip.  Each instant of it goes to the innermost host span
+  (the shortest, the window aside) that covers that instant, or to
+  ``host:other``, so a gap that crosses spans is split among them.
+
+``reduce`` returns ``window_s`` and ``busy_s``, ``n_devices``, and
+``host_spans`` (``{name: [count, seconds]}`` of the spans that start in
+the window, each clipped to its end), ``op_seconds`` (``{short name:
+seconds}`` of every op on the first chip), and for the breakdown the
+longest of those, ``device_ops``, and ``idle_gaps``, each as ``[[name,
+seconds], ...]``.
 """
 from __future__ import annotations
 
@@ -23,11 +37,13 @@ import re
 from typing import Any, Dict, List, Optional, Tuple
 
 WINDOW = "bench.window"
+SPAN_NAME = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
-CONTAINERS = {"while", "conditional", "call"}
+OTHER = "host:other"
 
 Interval = Tuple[float, float]
+Span = Tuple[str, float, float]
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:
@@ -67,33 +83,90 @@ def short_name(hlo: str) -> str:
     return (f"{lhs} {kind} {result}" if kind else lhs)[:160]
 
 
-def op_times(ops: List[Tuple[str, float, float]], lo: float, hi: float
-             ) -> Dict[str, float]:
-    """Seconds per op inside [lo, hi], by short name, leaving out the ops
-    that hold other ops."""
+def op_times(ops: List[Span], lo: float, hi: float) -> Dict[str, float]:
+    """Seconds per op inside [lo, hi], by short name, each op's own: every
+    instant goes to the latest-started op that covers it, so an op that
+    holds others (``while``, ``conditional``, ``call``) keeps only what its
+    body's ops leave uncovered, and the seconds of all ops add up to the
+    union of their intervals."""
     out: Dict[str, float] = collections.defaultdict(float)
-    for name, s, e in ops:
-        if e > lo and s < hi and _parse(name)[1] not in CONTAINERS:
-            out[short_name(name)] += min(e, hi) - max(s, lo)
+    names: Dict[str, str] = {}
+    stack: List[List[Any]] = []   # [end, short name, own seconds], by start
+    clipped = sorted(((max(s, lo), -min(e, hi), n) for n, s, e in ops
+                      if e > lo and s < hi))
+    for s, neg_e, name in clipped:
+        e = -neg_e
+        while stack and s >= stack[-1][0]:
+            _, n, t = stack.pop()
+            out[n] += t
+        # [s, e] leaves the ops that held it: each instant was the topmost
+        # op's that had not yet ended
+        t = s
+        for entry in reversed(stack):
+            if t >= e:
+                break
+            if entry[0] > t:
+                taken = min(e, entry[0]) - t
+                entry[2] -= taken
+                t += taken
+        if name not in names:
+            names[name] = short_name(name)
+        stack.append([e, names[name], e - s])
+    for _, n, t in stack:
+        out[n] += t
+    return dict(out)
+
+
+def spans_in(spans: List[Span], lo: float, hi: float
+             ) -> Dict[str, List[float]]:
+    """``{name: [count, seconds]}`` over the spans that start in [lo, hi),
+    each clipped to ``hi``."""
+    out: Dict[str, List[float]] = {}
+    for n, s, e in spans:
+        if lo <= s < hi:
+            c = out.setdefault(n, [0, 0.0])
+            c[0] += 1
+            c[1] += min(e, hi) - s
     return out
 
 
+def split_gaps(spans: List[Span], gaps: List[Interval]) -> Dict[str, float]:
+    """Seconds of the gaps (in time order, not overlapping) by the innermost
+    span that covers each instant, or ``host:other``."""
+    by_start = sorted(spans, key=lambda sp: sp[1])
+    out: Dict[str, float] = collections.defaultdict(float)
+    i, live = 0, []
+    for gs, ge in gaps:
+        while i < len(by_start) and by_start[i][1] < ge:
+            live.append(by_start[i])
+            i += 1
+        live = [sp for sp in live if sp[2] > gs]
+        cuts = sorted({gs, ge} | {x for _, s, e in live for x in (s, e)
+                                  if gs < x < ge})
+        for a, b in zip(cuts, cuts[1:]):
+            covering = [(e - s, n) for n, s, e in live if s <= a and b <= e]
+            out[min(covering)[1] if covering else OTHER] += b - a
+    return out
+
+
+def _top(d: Dict[str, float], top: int) -> List[List[Any]]:
+    return [[n, t] for n, t in sorted(d.items(), key=lambda kv: -kv[1])[:top]]
+
+
 def reduce(path: str, top: int = 10) -> Dict[str, Any]:
-    """Reduce one ``.xplane.pb``.  Returns ``busy_s`` and ``window_s`` (None
-    where the trace holds no device or no window), ``device_ops`` and
-    ``idle_gaps`` as ``[[name, seconds], ...]``, and ``n_devices``."""
+    """Reduce one ``.xplane.pb`` (see the module's docstring).  ``busy_s``
+    and ``window_s`` are None, and the rest empty, where the trace holds no
+    device or no window."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
-    host: List[Tuple[str, float, float]] = []
-    devices: List[List[Tuple[str, float, float]]] = []
-    for plane in data.planes:
+    host: List[Span] = []
+    devices: List[List[Span]] = []
+    for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith("bench."):
-                        host.append((ev.name, ev.start_ns * 1e-9,
-                                     (ev.start_ns + ev.duration_ns) * 1e-9))
+            host += [(ev.name, ev.start_ns * 1e-9,
+                      (ev.start_ns + ev.duration_ns) * 1e-9)
+                     for line in plane.lines for ev in line.events
+                     if SPAN_NAME.match(ev.name)]
         elif DEVICE_PLANE.match(plane.name):
             ops = [(ev.name, ev.start_ns * 1e-9,
                     (ev.start_ns + ev.duration_ns) * 1e-9)
@@ -101,29 +174,21 @@ def reduce(path: str, top: int = 10) -> Dict[str, Any]:
                    for ev in line.events]
             if ops:
                 devices.append(ops)
+    out: Dict[str, Any] = {"busy_s": None, "window_s": None,
+                           "n_devices": len(devices), "host_spans": {},
+                           "op_seconds": {}, "device_ops": [], "idle_gaps": []}
     windows = [(s, e) for n, s, e in host if n == WINDOW]
-    out: Dict[str, Any] = {"busy_s": None, "window_s": None, "device_ops": [],
-                           "idle_gaps": [], "n_devices": len(devices)}
     if not windows or not devices:
         return out
     lo, hi = windows[0]
-    out["window_s"] = hi - lo
     busy = [merge(_clip([(s, e) for _, s, e in ops], lo, hi)) for ops in devices]
-    out["busy_s"] = sum(sum(e - s for s, e in b) for b in busy) / len(busy)
-
-    per_op = op_times(devices[0], lo, hi)
-    out["device_ops"] = [[n, t] for n, t in
-                         sorted(per_op.items(), key=lambda kv: -kv[1])[:top]]
-
-    spans = [(n, s, e) for n, s, e in host if n != WINDOW]
-    per_label: Dict[str, float] = collections.defaultdict(float)
+    spans = [sp for sp in host if sp[0] != WINDOW]
     edges = [lo] + [x for iv in busy[0] for x in iv] + [hi]
-    for gs, ge in zip(edges[0::2], edges[1::2]):
-        if ge <= gs:
-            continue
-        mid = 0.5 * (gs + ge)
-        covering = [(e - s, n) for n, s, e in spans if s <= mid <= e]
-        per_label[min(covering)[1] if covering else "host:other"] += ge - gs
-    out["idle_gaps"] = [[n, t] for n, t in
-                        sorted(per_label.items(), key=lambda kv: -kv[1])[:top]]
+    gaps = [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
+    op_seconds = op_times(devices[0], lo, hi)
+    out.update(window_s=hi - lo,
+               busy_s=sum(sum(e - s for s, e in b) for b in busy) / len(busy),
+               host_spans=spans_in(spans, lo, hi), op_seconds=op_seconds,
+               device_ops=_top(op_seconds, top),
+               idle_gaps=_top(split_gaps(spans, gaps), top))
     return out
