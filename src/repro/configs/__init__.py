@@ -22,9 +22,10 @@ ARCH_IDS: List[str] = [
     "smollm_360m",
     "hymba_1p5b",
     "mistral_large_123b",
+    "granite_4_0_h_micro",
 ]
 
-# extra pool architectures (beyond the 10 assigned; see README)
+# extra pool architectures (beyond those above; see README)
 EXTRA_ARCH_IDS: List[str] = [
     "llama3_8b",
     "mixtral_8x7b",
@@ -44,6 +45,7 @@ ALIASES: Dict[str, str] = {
     "mistral-large-123b": "mistral_large_123b",
     "llama3-8b": "llama3_8b",
     "mixtral-8x7b": "mixtral_8x7b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

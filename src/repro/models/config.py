@@ -55,6 +55,16 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 256
     ssm_groups: int = 1
+    ssm_conv_bias: bool = False
+    # --- per-layer pattern ("mamba" | "attention" per layer; empty: the
+    # family decides) and scalar multipliers (GraniteMoeHybrid); each
+    # default adds no operation
+    layer_types: Tuple[str, ...] = ()
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attn_scale: float = 0.0      # softmax scale; 0 = 1/sqrt(head_dim)
+    logits_scaling: float = 1.0  # logits divided by it
+    rope: bool = True            # False: no positional encoding (NoPE)
     # --- hybrid / attention variants
     swa_window: int = 0          # 0 = full attention everywhere
     global_layers: Tuple[int, ...] = ()  # layer indices using full attn when swa_window>0

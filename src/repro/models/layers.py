@@ -78,10 +78,16 @@ def _mask_bias(pos_q: jax.Array, pos_kv: jax.Array, causal: bool,
     return jnp.where(ok, 0.0, -jnp.inf).astype(jnp.float32)
 
 
+def _scale(q: jax.Array, scale: Optional[float]) -> float:
+    return 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+
+
 def _attn_block(q: jax.Array, k: jax.Array, v: jax.Array,
-                bias: jax.Array, head_mask: Optional[jax.Array]) -> jax.Array:
-    """q (B,Sq,KV,G,D), k/v (B,Skv,KV,D), bias (Sq,Skv) -> (B,Sq,KV,G,D)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+                bias: jax.Array, head_mask: Optional[jax.Array],
+                scale: Optional[float] = None) -> jax.Array:
+    """q (B,Sq,KV,G,D), k/v (B,Skv,KV,D), bias (Sq,Skv) -> (B,Sq,KV,G,D);
+    the scores are scaled by ``scale``, by default 1/sqrt(D)."""
+    scale = _scale(q, scale)
     s = jnp.einsum("bskgd,btkd->bsktg", q, k,
                    preferred_element_type=jnp.float32) * scale
     s = s + bias[None, :, None, :, None]
@@ -102,6 +108,7 @@ def attention(
     window: int = 0,
     q_chunk: int = 0,
     head_mask: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Padded-GQA attention.
 
@@ -111,13 +118,14 @@ def attention(
     q_chunk > 0 = memory-efficient chunked path (scan over query blocks);
     with a window it also *slices* the kv stream so FLOPs are O(S*window).
     head_mask: (KVp, G) zeros out padded q slots exactly.
+    scale: the softmax scale, by default 1/sqrt(Dh).
     """
     B, Sq, KV, G, Dh = q.shape
     if q_chunk <= 0 or Sq <= q_chunk or Sq % q_chunk != 0:
         # indivisible sequences (e.g. whisper's 1500 encoder frames) take
         # the one-shot path; chunking is a memory optimisation only
         bias = _mask_bias(pos_q, pos_kv, causal, window)
-        return _attn_block(q, k, v, bias, head_mask)
+        return _attn_block(q, k, v, bias, head_mask, scale)
     n_chunks = Sq // q_chunk
 
     if window > 0 and window % q_chunk == 0 and k.shape[1] == Sq:
@@ -136,7 +144,7 @@ def attention(
             pq = jax.lax.dynamic_slice_in_dim(pos_q, i * q_chunk, q_chunk)
             pk = jax.lax.dynamic_slice_in_dim(pos_kv_p, i * q_chunk, window + q_chunk)
             bias = _mask_bias(pq, pk, causal, window)
-            return _attn_block(qs, ks, vs, bias, head_mask)
+            return _attn_block(qs, ks, vs, bias, head_mask, scale)
 
         _, outs = jax.lax.scan(lambda c, i: (c, chunk_body(i)), None,
                                jnp.arange(n_chunks))
@@ -148,7 +156,7 @@ def attention(
         qs = jax.lax.dynamic_slice_in_dim(q, i * q_chunk, q_chunk, axis=1)
         pq = jax.lax.dynamic_slice_in_dim(pos_q, i * q_chunk, q_chunk)
         bias = _mask_bias(pq, pos_kv, causal, window)
-        return _attn_block(qs, k, v, bias, head_mask)
+        return _attn_block(qs, k, v, bias, head_mask, scale)
 
     _, outs = jax.lax.scan(lambda c, i: (c, chunk_body(i)), None,
                            jnp.arange(n_chunks))
@@ -162,14 +170,16 @@ def decode_attention(
     pos_q: jax.Array, pos_kv: jax.Array,
     window: int = 0,
     head_mask: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """One query token against a read-only cache and against itself.
 
     q: (B, 1, KVp, G, Dh); k, v: (B, T, KVp, Dh), the cache, masked by
     pos_kv as ``attention`` masks it (causal); k_new, v_new: (B, 1, KVp,
     Dh), the token's own, always attended.  The cache's scores and the
-    token's own share one softmax, so the cache is never concatenated."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    token's own share one softmax, so the cache is never concatenated.
+    ``scale`` as in ``attention``."""
+    scale = _scale(q, scale)
     bias = _mask_bias(pos_q, pos_kv, True, window)
     s = jnp.einsum("bskgd,btkd->bsktg", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -296,9 +306,10 @@ def moe_ffn(
 
 
 def causal_conv1d(x: jax.Array, w: jax.Array,
-                  cache: Optional[jax.Array] = None
+                  cache: Optional[jax.Array] = None,
+                  bias: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Depthwise causal conv.  x (B,S,Cch), w (Cch,K).
+    """Depthwise causal conv.  x (B,S,Cch), w (Cch,K), bias (Cch,) or None.
     cache (B,K-1,Cch) for decode; returns (y, new_cache)."""
     B, S, Cch = x.shape
     K = w.shape[1]
@@ -314,6 +325,8 @@ def causal_conv1d(x: jax.Array, w: jax.Array,
         window_strides=(1,), padding="VALID",
         dimension_numbers=("NWC", "WIO", "NWC"),
         feature_group_count=Cch)
+    if bias is not None:
+        y = y + bias
     return y, new_cache
 
 
@@ -396,7 +409,13 @@ def ssd_decode_step(
     a = -jnp.exp(A_log.astype(jnp.float32))
     dA = jnp.exp(dt.astype(jnp.float32) * a)               # (B,H)
     xf = x.astype(jnp.float32)
-    new_state = (state.astype(jnp.float32) * dA[:, :, None, None]
-                 + jnp.einsum("bh,bhp,bhn->bhpn", dt.astype(jnp.float32), xf, Bh))
-    y = jnp.einsum("bhn,bhpn->bhp", Ch, new_state) + D[None, :, None] * xf
+    dtf = dt.astype(jnp.float32)
+    old = state.astype(jnp.float32)
+    new_state = (old * dA[:, :, None, None]
+                 + jnp.einsum("bh,bhp,bhn->bhpn", dtf, xf, Bh))
+    # C.h_new = dA C.h_old + dt (C.B) x: read from the old state, so that
+    # the new state's only consumer is its write (in place, where donated)
+    y = (dA[:, :, None] * jnp.einsum("bhn,bhpn->bhp", Ch, old)
+         + (dtf * jnp.einsum("bhn,bhn->bh", Ch, Bh))[:, :, None] * xf
+         + D[None, :, None] * xf)
     return y.astype(x.dtype), new_state.astype(state.dtype)

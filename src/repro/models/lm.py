@@ -9,7 +9,12 @@ around each layer.  Kinds:
   swa     — same, sliding-window attention       (hybrid/serving variant)
   moe     — GQA attention + routed-expert FFN (+ optional shared experts)
   ssm     — Mamba-2 SSD mixer                    (attention-free)
+  ssm_mlp — Mamba-2 SSD mixer, then gated MLP    (``layer_types`` "mamba")
   hybrid  — parallel attention + SSD heads, then MLP (hymba)
+
+A config with ``layer_types`` (GraniteMoeHybrid) runs its layers in that
+order: each run of "mamba" layers is an ``ssm_mlp`` group, each run of
+"attention" layers an ``attn`` group.
 
 Decode ("serve") uses per-group caches: KV ring buffers for attention,
 (state, conv) tuples for SSD.
@@ -17,6 +22,7 @@ Decode ("serve") uses per-group caches: KV ring buffers for attention,
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -34,13 +40,18 @@ NEG_INF = -1e9
 
 @dataclasses.dataclass(frozen=True)
 class BlockGroup:
-    kind: str          # attn | swa | moe | ssm | hybrid
+    kind: str          # attn | swa | moe | ssm | ssm_mlp | hybrid
     count: int
     window: int = 0    # >0 for swa kind
 
 
 def block_groups(cfg: ModelConfig, *, serve_longctx: bool = False) -> List[BlockGroup]:
     """Static layer grouping for a config (DESIGN.md §4)."""
+    if cfg.layer_types:
+        assert len(cfg.layer_types) == cfg.n_layers, cfg.arch_id
+        kinds = {"mamba": "ssm_mlp", "attention": "attn"}
+        return [BlockGroup(kinds[t], len(list(run)))
+                for t, run in itertools.groupby(cfg.layer_types)]
     if cfg.family == "ssm":
         return [BlockGroup("ssm", cfg.n_layers)]
     if cfg.family == "hybrid":
@@ -138,7 +149,13 @@ def _ssm_desc(cfg: ModelConfig, plan: PadPlan) -> Dict[str, Any]:
     GN = cfg.ssm_groups * cfg.ssm_state
     K = cfg.ssm_conv
     inner = Hp * P
+    bias = {}
+    if cfg.ssm_conv_bias:
+        bias = {"conv_x_b": LeafSpec((inner,), ("inner",), "normal:0.5"),
+                "conv_B_b": LeafSpec((GN,), (None,), "normal:0.5"),
+                "conv_C_b": LeafSpec((GN,), (None,), "normal:0.5")}
     return {
+        **bias,
         "ln": LeafSpec((D,), ("d_model",), "ones"),
         "wz": LeafSpec((D, inner), ("d_model", "inner")),
         "wx": LeafSpec((D, inner), ("d_model", "inner")),
@@ -169,6 +186,8 @@ def _block_desc(cfg: ModelConfig, plan: PadPlan, kind: str) -> Dict[str, Any]:
         }
     if kind == "ssm":
         return _ssm_desc(cfg, plan)
+    if kind == "ssm_mlp":
+        return {**_ssm_desc(cfg, plan), **_mlp_desc(cfg)}
     if kind == "moe":
         return {**_attn_desc(cfg, plan), **_moe_desc(cfg, plan)}
     return {**_attn_desc(cfg, plan), **_mlp_desc(cfg)}  # attn / swa
@@ -212,10 +231,29 @@ def _project_qkv(cfg, plan, p, h, positions):
     if cfg.qk_norm:
         q = L.rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = L.rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = L.rope(q, positions, cfg.rope_theta)
+        k = L.rope(k, positions, cfg.rope_theta)
     q = lc(q, "batch", "seq", "heads", None)
     return q, k, v
+
+
+def _attn_scale(cfg: ModelConfig) -> Optional[float]:
+    """The softmax scale the config sets, or None for 1/sqrt(head_dim)."""
+    return cfg.attn_scale or None
+
+
+def _residual(cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    """``x + y``, the branch ``y`` scaled by the residual multiplier where
+    one is set."""
+    r = cfg.residual_multiplier
+    return x + (y if r == 1.0 else y * r)
+
+
+def _embed(cfg: ModelConfig, params, tokens, compute_dtype) -> jax.Array:
+    x = jnp.take(params["embed"].astype(compute_dtype), tokens, axis=0)
+    m = cfg.embedding_multiplier
+    return x if m == 1.0 else x * m
 
 
 def _attn_out(cfg, plan, p, attn, B, S):
@@ -251,15 +289,16 @@ def attn_block(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
     hm = jnp.asarray(plan.head_mask(), x.dtype).reshape(plan.kv_pad, plan.group, 1)
     attn = L.attention(q, k, v, pos_q=positions, pos_kv=positions,
                        causal=True, window=window, q_chunk=q_chunk,
-                       head_mask=hm)
-    return x + _attn_out(cfg, plan, p, attn, B, S)
+                       head_mask=hm, scale=_attn_scale(cfg))
+    return _residual(cfg, x, _attn_out(cfg, plan, p, attn, B, S))
 
 
 def mlp_block(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
     h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-    return x + L.gated_mlp(h, p["w1"].astype(x.dtype),
-                           p.get("w3") if p.get("w3") is None else p["w3"].astype(x.dtype),
-                           p["w2"].astype(x.dtype), cfg.act)
+    return _residual(cfg, x, L.gated_mlp(
+        h, p["w1"].astype(x.dtype),
+        p.get("w3") if p.get("w3") is None else p["w3"].astype(x.dtype),
+        p["w2"].astype(x.dtype), cfg.act))
 
 
 def moe_block(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
@@ -287,7 +326,7 @@ def moe_block(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
 def ssm_block(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
               x: jax.Array) -> jax.Array:
     y, _ = ssm_mixer(cfg, plan, p, L.rmsnorm(x, p["ln"], cfg.norm_eps))
-    return x + y
+    return _residual(cfg, x, y)
 
 
 def ssm_mixer(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
@@ -306,15 +345,16 @@ def ssm_mixer(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
     z = lc(z, "batch", "seq", "inner")
     xs = lc(xs, "batch", "seq", "inner")
 
+    def conv(u, name):
+        bias = p.get(name + "_b")
+        return L.causal_conv1d(
+            u, p[name].astype(h.dtype), None if cache is None else cache[name],
+            None if bias is None else bias.astype(h.dtype))
+
+    (xs, cx), (Bs, cb), (Cs, cc) = (conv(xs, "conv_x"), conv(Bs, "conv_B"),
+                                    conv(Cs, "conv_C"))
     new_cache: Optional[Dict[str, jax.Array]] = None
-    if cache is None:
-        xs, _ = L.causal_conv1d(xs, p["conv_x"].astype(h.dtype))
-        Bs, _ = L.causal_conv1d(Bs, p["conv_B"].astype(h.dtype))
-        Cs, _ = L.causal_conv1d(Cs, p["conv_C"].astype(h.dtype))
-    else:
-        xs, cx = L.causal_conv1d(xs, p["conv_x"].astype(h.dtype), cache["conv_x"])
-        Bs, cb = L.causal_conv1d(Bs, p["conv_B"].astype(h.dtype), cache["conv_B"])
-        Cs, cc = L.causal_conv1d(Cs, p["conv_C"].astype(h.dtype), cache["conv_C"])
+    if cache is not None:
         new_cache = {"conv_x": cx, "conv_B": cb, "conv_C": cc}
     xs, Bs, Cs = jax.nn.silu(xs), jax.nn.silu(Bs), jax.nn.silu(Cs)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
@@ -358,7 +398,8 @@ def hybrid_block(cfg: ModelConfig, plan: PadPlan, p: Dict[str, Any],
     v = L.duplicate_kv(v, plan)
     hm = jnp.asarray(plan.head_mask(), x.dtype).reshape(plan.kv_pad, plan.group, 1)
     attn = L.attention(q, k, v, pos_q=positions, pos_kv=positions,
-                       causal=True, window=window, q_chunk=q_chunk, head_mask=hm)
+                       causal=True, window=window, q_chunk=q_chunk, head_mask=hm,
+                       scale=_attn_scale(cfg))
     a_out = _attn_out(cfg, plan, p, attn, B, S)
     s_out, _ = ssm_mixer(cfg, plan, p["ssm"], h)
     fused = 0.5 * (L.rmsnorm(a_out, p["attn_fuse_norm"], cfg.norm_eps)
@@ -380,7 +421,7 @@ def forward(cfg: ModelConfig, plan: PadPlan, params: Dict[str, Any],
     """tokens (B,S) -> (hidden (B,S,D), total_aux_loss)."""
     B, S = tokens.shape
     groups = block_groups(cfg, serve_longctx=serve_longctx)
-    x = jnp.take(params["embed"].astype(compute_dtype), tokens, axis=0)
+    x = _embed(cfg, params, tokens, compute_dtype)
     x = lc(x, "batch", "seq_res", None)
     positions = jnp.arange(S, dtype=jnp.int32)
     aux_total = jnp.zeros((), jnp.float32)
@@ -391,6 +432,9 @@ def forward(cfg: ModelConfig, plan: PadPlan, params: Dict[str, Any],
         def layer_fn(x, pl, g=g):
             if g.kind == "ssm":
                 return ssm_block(cfg, plan, pl, x), jnp.zeros((), jnp.float32)
+            if g.kind == "ssm_mlp":
+                return (mlp_block(cfg, pl, ssm_block(cfg, plan, pl, x)),
+                        jnp.zeros((), jnp.float32))
             if g.kind in ("hybrid", "hybrid_swa"):
                 return (hybrid_block(cfg, plan, pl, x, positions,
                                      window=g.window, q_chunk=q_chunk),
@@ -423,11 +467,17 @@ def logits_from_hidden(cfg: ModelConfig, plan: PadPlan, params, x: jax.Array
         unembed = params["embed"].T
     logits = jnp.einsum("bsd,dv->bsv", x, unembed.astype(x.dtype))
     logits = lc(logits, "batch", "seq", "vocab")
+    logits = _scale_logits(cfg, logits)
     if plan.vocab_pad > cfg.vocab_size:
         pad_bias = jnp.where(jnp.arange(plan.vocab_pad) < cfg.vocab_size,
                              0.0, NEG_INF).astype(logits.dtype)
         logits = logits + pad_bias
     return logits
+
+
+def _scale_logits(cfg: ModelConfig, logits: jax.Array) -> jax.Array:
+    s = cfg.logits_scaling
+    return logits if s == 1.0 else logits / s
 
 
 def loss_fn(cfg: ModelConfig, plan: PadPlan, params,
@@ -454,7 +504,7 @@ def loss_fn(cfg: ModelConfig, plan: PadPlan, params,
     def chunk_nll(xc, yc):
         lg = jnp.einsum("btd,dv->btv", xc, unembed,
                         preferred_element_type=jnp.float32)
-        lg = lc(lg, "batch", "seq", "vocab")
+        lg = _scale_logits(cfg, lc(lg, "batch", "seq", "vocab"))
         if pad_bias is not None:
             lg = lg + pad_bias
         lse = jax.nn.logsumexp(lg, axis=-1)
@@ -503,7 +553,7 @@ def init_cache_desc(cfg: ModelConfig, plan: PadPlan, *, batch: int,
             gdesc["v"] = LeafSpec((n, batch, span, plan.kv_pad, hd),
                                   ("layers", "batch", None, "kv_heads", None),
                                   "zeros", dtype)
-        if g.kind in ("ssm", "hybrid", "hybrid_swa"):
+        if g.kind in ("ssm", "ssm_mlp", "hybrid", "hybrid_swa"):
             Hp, P = plan.ssm_heads_pad, cfg.ssm_head_dim
             GN = cfg.ssm_groups * cfg.ssm_state
             K = cfg.ssm_conv
@@ -553,7 +603,8 @@ def _decode_attn_read(cfg, plan, p, x, kcache, vcache, pos, window):
     pos_kv = jnp.where(idx == _write_index(pos, span, window), -1, pos_kv)
     hm = jnp.asarray(plan.head_mask(), x.dtype).reshape(plan.kv_pad, plan.group, 1)
     attn = L.decode_attention(q, kcache, vcache, k, v, pos_q=positions,
-                              pos_kv=pos_kv, window=window, head_mask=hm)
+                              pos_kv=pos_kv, window=window, head_mask=hm,
+                              scale=_attn_scale(cfg))
     out = _attn_out(cfg, plan, p, attn, B, 1)
     return out, k, v
 
@@ -567,6 +618,24 @@ def _decode_attn(cfg, plan, p, x, kcache, vcache, pos, window):
             jax.lax.dynamic_update_slice_in_dim(vcache, v, at, axis=1))
 
 
+def _layer_loop(layer_fn, carry, gp, n: int, unroll, static: bool):
+    """``lax.scan(layer_fn, carry, (gp, arange(n)), unroll=unroll)``, or with
+    ``static`` the same loop written out with each layer's index a
+    constant: a read of layer ``i``'s slice of the carried SSM state and
+    the write back into it are then provably the same slice, which the
+    compiler updates in place (under the batcher's vmap a traced index
+    turns them into a gather and a scatter, and the gathered slice is
+    copied first).  Groups with no SSM state keep the scan: written out,
+    a dense group compiles to another program than the scan's."""
+    if not static:
+        return jax.lax.scan(layer_fn, carry, (gp, jnp.arange(n)), unroll=unroll)
+    ys = []
+    for i in range(n):
+        carry, y = layer_fn(carry, (jax.tree.map(lambda a: a[i], gp), i))
+        ys.append(y)
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+
 def serve_step(cfg: ModelConfig, plan: PadPlan, params,
                cache: Dict[str, Any], tokens: jax.Array, pos: jax.Array,
                *, compute_dtype: Any = jnp.float32,
@@ -578,42 +647,58 @@ def serve_step(cfg: ModelConfig, plan: PadPlan, params,
     indexed, not passed as scan xs/ys): each layer's new k/v leave the
     loop as small ys and are written once per leaf after it, so a donated
     cache is updated in place, not copied or relaid out.  SSM state and
-    conv caches, small and rewritten whole each step, ride the scan."""
+    conv caches, rewritten whole each step, ride the loop's carry: layer
+    ``i`` reads its slice and writes its new one back into the same array,
+    so a donated state is updated in place too, never stacked anew."""
     groups = block_groups(cfg, serve_longctx=serve_longctx)
-    x = jnp.take(params["embed"].astype(compute_dtype), tokens, axis=0)
+    x = _embed(cfg, params, tokens, compute_dtype)
     new_cache: Dict[str, Any] = {}
+
+    def at_layer(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+    def set_layer(tree, new, i):
+        return jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+            tree, new)
 
     for gi, g in enumerate(groups):
         gp = params[f"g{gi}"]
         gc = cache[f"g{gi}"]
 
-        def layer_fn(x, packed, g=g, gc=gc):
-            pl, i, ssm_c = packed
-            if g.kind == "ssm":
+        def layer_fn(carry, packed, g=g, gc=gc):
+            x, ssm_all = carry
+            pl, i = packed
+            if g.kind in ("ssm", "ssm_mlp"):
                 h = L.rmsnorm(x, pl["ln"], cfg.norm_eps)
-                y, ssm_cache = ssm_mixer(cfg, plan, pl, h, cache=ssm_c)
-                return x + y, (None, None, ssm_cache)
+                y, ssm_c = ssm_mixer(cfg, plan, pl, h,
+                                     cache=at_layer(ssm_all, i))
+                x = _residual(cfg, x, y)
+                if g.kind == "ssm_mlp":
+                    x = mlp_block(cfg, pl, x)
+                return (x, set_layer(ssm_all, ssm_c, i)), (None, None)
             h = L.rmsnorm(x, pl["ln1"], cfg.norm_eps)
             kc = jax.lax.dynamic_index_in_dim(gc["k"], i, keepdims=False)
             vc = jax.lax.dynamic_index_in_dim(gc["v"], i, keepdims=False)
             a_out, k, v = _decode_attn_read(cfg, plan, pl, x, kc, vc, pos,
                                             g.window)
             if g.kind in ("hybrid", "hybrid_swa"):
-                s_out, ssm_cache = ssm_mixer(cfg, plan, pl["ssm"], h,
-                                             cache=ssm_c)
+                s_out, ssm_c = ssm_mixer(cfg, plan, pl["ssm"], h,
+                                         cache=at_layer(ssm_all, i))
                 fused = 0.5 * (L.rmsnorm(a_out, pl["attn_fuse_norm"], cfg.norm_eps)
                                + L.rmsnorm(s_out, pl["ssm_fuse_norm"], cfg.norm_eps))
                 x = x + fused
-                return mlp_block(cfg, pl, x), (k, v, ssm_cache)
-            x = x + a_out
+                return (mlp_block(cfg, pl, x), set_layer(ssm_all, ssm_c, i)), (k, v)
+            x = _residual(cfg, x, a_out)
             if g.kind == "moe":
                 x, _ = moe_block(cfg, plan, pl, x, n_token_groups)
-                return x, (k, v, None)
-            return mlp_block(cfg, pl, x), (k, v, None)
+                return (x, ssm_all), (k, v)
+            return (mlp_block(cfg, pl, x), ssm_all), (k, v)
 
-        x, (ks, vs, ssm_cache) = jax.lax.scan(
-            layer_fn, x, (gp, jnp.arange(g.count), gc.get("ssm")),
-            unroll=scan_unroll)
+        (x, ssm_cache), (ks, vs) = _layer_loop(
+            layer_fn, (x, gc.get("ssm")), gp, g.count, scan_unroll,
+            static=scan_unroll is True and "ssm" in gc)
         ngc: Dict[str, Any] = {}
         if "k" in gc:
             at = _write_index(pos, gc["k"].shape[2], g.window)

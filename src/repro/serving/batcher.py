@@ -12,6 +12,13 @@ This requires per-slot decode positions, which the single-``pos`` serve
 step doesn't expose — so the batcher drives the model with a vmapped
 single-sequence step over the slot axis.  Sampling: greedy or
 temperature.
+
+A slot holds whatever decode state the model's layers keep: a KV cache
+for attention layers, and for Mamba-2 layers (``ssm``) the recurrent
+state and the conv windows.  Admission resets all of it in place
+(``serve.reset_slot``, counter ``serving.slot_resets``); gauges
+``serving.slot_kv_bytes`` and ``serving.slot_ssm_bytes`` give one slot's
+bytes of each.
 """
 from __future__ import annotations
 
@@ -20,7 +27,7 @@ import functools
 import itertools
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,10 +72,23 @@ def _slot_step_for(model: Model):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _reset_slot(cache, empty, s):
     """Slot ``s`` of the slot-stacked ``cache`` set to ``empty``, in place:
-    one executable for every slot, touching that slot's entries alone."""
+    one executable for every slot, touching that slot's entries alone (its
+    KV cache, SSM state and conv windows)."""
     return jax.tree.map(
         lambda full, e: jax.lax.dynamic_update_index_in_dim(full, e, s, 0),
         cache, empty)
+
+
+def slot_bytes(cache) -> Tuple[int, int]:
+    """(KV bytes, SSM state and conv window bytes) of one slot's cache."""
+    kv = ssm = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        n = leaf.size * leaf.dtype.itemsize
+        if any(getattr(k, "key", None) == "ssm" for k in path):
+            ssm += n
+        else:
+            kv += n
+    return kv, ssm
 
 
 @dataclasses.dataclass
@@ -105,6 +125,9 @@ class ContinuousBatcher:
         # slot-stacked cache: add a leading slot axis via vmap-compatible stack
         self.cache = jax.tree.map(
             lambda x: jnp.stack([x] * n_slots), self._empty_cache)
+        kv, ssm = slot_bytes(self._empty_cache)
+        obs_metrics.gauge("serving.slot_kv_bytes").set(kv)
+        obs_metrics.gauge("serving.slot_ssm_bytes").set(ssm)
 
         # params is an explicit argument (vmap in_axes=None), so the jitted
         # step is shared across batcher instances serving the same model
@@ -126,7 +149,9 @@ class ContinuousBatcher:
         self.queue.enqueue((time.perf_counter(), req))
 
     def _reset_slot_cache(self, s: int) -> None:
-        self.cache = _reset_slot(self.cache, self._empty_cache, np.int32(s))
+        with span("serve.reset_slot"):
+            self.cache = _reset_slot(self.cache, self._empty_cache, np.int32(s))
+        obs_metrics.counter("serving.slot_resets").inc()
 
     def _try_fill_slots(self) -> None:
         for s in range(self.n_slots):

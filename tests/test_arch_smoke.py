@@ -76,7 +76,8 @@ def test_serve_step_shapes(arch):
     assert jax.tree.structure(cache) == jax.tree.structure(new_cache)
 
 
-@pytest.mark.parametrize("arch", ["mamba2-2.7b", "hymba-1.5b", "qwen2-0.5b"])
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "hymba-1.5b", "qwen2-0.5b",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_forward(arch):
     """Greedy per-position decode logits == teacher-forced forward."""
     cfg = get_config(arch, smoke=True)
@@ -112,6 +113,7 @@ def test_full_configs_match_assignment():
         "smollm-360m": (32, 960, 15, 5, 2560, 49152),
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
         "mistral-large-123b": (88, 12288, 96, 8, 28672, 32768),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     for arch, (L, D, H, KV, FF, V) in spec.items():
         cfg = get_config(arch)
@@ -126,3 +128,12 @@ def test_full_configs_match_assignment():
     assert get_config("qwen2-moe-a2.7b").n_shared_experts == 4
     assert get_config("mamba2-2.7b").ssm_state == 128
     assert get_config("hymba-1.5b").ssm_state == 16
+    granite = get_config("granite-4.0-h-micro")
+    assert granite.layer_types.count("attention") == 4
+    assert [i for i, t in enumerate(granite.layer_types) if t == "attention"] == [
+        5, 15, 25, 35]
+    assert (granite.ssm_heads, granite.ssm_head_dim, granite.ssm_state,
+            granite.ssm_conv, granite.ssm_conv_bias) == (64, 64, 128, 4, True)
+    assert (granite.embedding_multiplier, granite.residual_multiplier,
+            granite.attn_scale, granite.logits_scaling, granite.rope) == (
+        12.0, 0.22, 1 / 64, 8.0, False)

@@ -151,11 +151,17 @@ def test_step_phases_are_profiler_spans(setup, tmp_path):
     assert len(outer) == steps
     for s0, e0 in outer:
         inner = [(n, s, e) for s, _, n, e in events
-                 if n != "serve.step" and s0 <= s and e <= e0]
+                 if n not in ("serve.step", "serve.reset_slot")
+                 and s0 <= s and e <= e0]
         assert [n for n, _, _ in inner] == list(SERVE_SPANS)
         # one after another, without overlap
         assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
-    assert len(events) == steps * (1 + len(SERVE_SPANS))
+    # each admission's slot reset is a span inside ``serve.admit``
+    resets = [(s, e) for s, _, n, e in events if n == "serve.reset_slot"]
+    admits = [(s, e) for s, _, n, e in events if n == "serve.admit"]
+    assert len(resets) == 2
+    assert all(any(a0 <= s and e <= a1 for a0, a1 in admits) for s, e in resets)
+    assert len(events) == steps * (1 + len(SERVE_SPANS)) + len(resets)
 
 
 
@@ -229,3 +235,87 @@ def test_step_donates_the_cache(setup):
     assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(batcher.cache))
     assert not any(leaf.is_deleted()
                    for leaf in jax.tree.leaves(batcher._empty_cache))
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    cfg = get_config("granite-4.0-h-micro", smoke=True)
+    model = Model.for_config(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    return cfg, model, params
+
+
+def _slot_leaves(cache, s):
+    """Slot ``s``'s leaves, by path."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(cache)
+    return {jax.tree_util.keystr(p): np.asarray(leaf[s]) for p, leaf in flat}
+
+
+def test_hybrid_slots_match_the_full_forward_and_reset_to_zero(hybrid):
+    """Mamba-2 and attention layers in one slot: each step's logits equal
+    the model's full forward pass over the request's tokens, for a slot
+    reused after another request too, whose SSM state, conv windows and
+    KV cache the admission's reset set to zero."""
+    cfg, model, params = hybrid
+    rs = np.random.RandomState(4)
+    sizes = [(7, 6), (2, 14), (4, 5)]
+    prompts = [list(rs.randint(0, cfg.vocab_size, (n,))) for n, _ in sizes]
+    batcher = ContinuousBatcher(model, params, n_slots=2, max_seq=32)
+    for i, (p, (_, k)) in enumerate(zip(prompts, sizes)):
+        batcher.submit(Request(rid=i, prompt=p, max_new_tokens=k))
+    rows, served_by, resets = {}, {}, []
+    sample, reset = batcher._sample, batcher._reset_slot_cache
+
+    def record(live, logits_np):
+        for s in live:
+            rid = batcher.slot_req[s].rid
+            rows.setdefault(rid, []).append(logits_np[s].copy())
+            served_by.setdefault(rid, s)
+        return sample(live, logits_np)
+
+    def record_reset(s):
+        before = _slot_leaves(batcher.cache, s)
+        reset(s)
+        resets.append((s, before, _slot_leaves(batcher.cache, s)))
+
+    batcher._sample = record
+    batcher._reset_slot_cache = record_reset
+    batcher.run_until_drained()
+    assert served_by == {0: 0, 1: 1, 2: 0}
+    s, before, after = resets[2]
+    assert s == 0
+    ssm = [k for k in after if "'ssm'" in k]
+    assert {k.rsplit("'", 2)[-2] for k in ssm} == {"state", "conv_x", "conv_B",
+                                                  "conv_C"}
+    assert all(np.any(before[k]) for k in ssm)  # the first request's state
+    assert not any(np.any(v) for v in after.values())
+    for i, p in enumerate(prompts):
+        served = batcher.results[i].tokens
+        full = model.forward_logits(
+            params, {"tokens": jnp.asarray([p + served[:-1]], jnp.int32)})[0]
+        np.testing.assert_allclose(np.stack(rows[i]), full, rtol=1e-5,
+                                   atol=1e-5, err_msg=str(i))
+
+
+def test_slot_bytes_and_resets_are_recorded(setup, hybrid):
+    """The batcher sets one slot's KV and SSM bytes as gauges when it is
+    built, and counts each slot reset."""
+    for cfg, model, params in (setup, hybrid):
+        batcher = ContinuousBatcher(model, params, n_slots=2, max_seq=32)
+        one = model.init_cache_desc(batch=1, max_seq=32)
+        flat, _ = jax.tree_util.tree_flatten_with_path(
+            one, is_leaf=lambda x: hasattr(x, "shape") and hasattr(x, "init"))
+        want = {"kv": 0, "ssm": 0}
+        for p, spec in flat:
+            n = int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize
+            want["ssm" if any(getattr(k, "key", None) == "ssm" for k in p)
+                 else "kv"] += n
+        assert obs_metrics.gauge("serving.slot_kv_bytes").value == want["kv"] > 0
+        assert obs_metrics.gauge("serving.slot_ssm_bytes").value == want["ssm"]
+        assert (want["ssm"] > 0) == (cfg.family == "hybrid")
+        resets = obs_metrics.counter("serving.slot_resets")
+        count0 = resets.value
+        for i in range(3):
+            batcher.submit(Request(rid=i, prompt=[1, 2], max_new_tokens=2))
+        batcher.run_until_drained()
+        assert resets.value - count0 == 3

@@ -191,3 +191,43 @@ def test_qwen2_slot_reset_updates_one_slot_in_place_on_v5e(one_chip):
     copies = [(op, ty, dims) for op, ty, dims in _hlo_ops(compiled.as_text())
               if op in ("copy", "copy-start") and ty == "f32"]
     assert not copies, copies
+
+
+def test_granite_slot_step_updates_the_state_in_place_on_v5e(one_chip):
+    """granite-4.0-h-micro's slot step, cut to two periods (18 Mamba-2 and
+    2 attention layers) at published widths, 64 slots x 2048, aliases the
+    donated cache, and rewrites each layer's SSM state in place: the only
+    ops whose result is a group's whole state are the 18 layer updates
+    (fusions), and no op yields one layer's state for all slots (a copy
+    of it before its update)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.api import Model
+    from repro.models.params import abstract_params
+    from repro.serving.batcher import _slot_step_for
+
+    full = get_config("granite-4.0-h-micro")
+    cfg = dataclasses.replace(full, n_layers=20, layer_types=full.layer_types[:20])
+    n, span = 64, 2048
+    model = Model.for_config(cfg)
+    one = abstract_params(model.init_cache_desc(batch=1, max_seq=span))
+    cache = _on(one_chip, jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((n,) + s.shape, s.dtype), one))
+    compiled = _slot_step_for(model).lower(
+        _on(one_chip, model.abstract_params()), cache,
+        _on(one_chip, jax.ShapeDtypeStruct((n, 1), jnp.int32)),
+        _on(one_chip, jax.ShapeDtypeStruct((n,), jnp.int32))).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    state = "64,64,128"   # a layer's state: 64 heads of 64 x state 128
+    groups = {f"{n},{g},1,{state}" for g in (5, 9, 4)}
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]   # not the fusions' insides
+    ops = [(op, dims) for op, _, dims in _hlo_ops(entry)
+           if dims.endswith(state) and op != "parameter"]
+    assert sorted(dims for op, dims in ops) == sorted(
+        [f"{n},5,1,{state}"] * 5 + [f"{n},9,1,{state}"] * 9
+        + [f"{n},4,1,{state}"] * 4), ops
+    assert all(op == "fusion" and dims in groups for op, dims in ops), ops
