@@ -11,7 +11,12 @@ depths coexist in one cache.
 This requires per-slot decode positions, which the single-``pos`` serve
 step doesn't expose — so the batcher drives the model with a vmapped
 single-sequence step over the slot axis.  Sampling: greedy or
-temperature.
+temperature.  The step picks every slot's greedy token on the device
+(argmax over the vocabulary), and only those int32 tokens reach the
+host; the logits stay on the device.  A request with ``temperature > 0``
+samples from its own slot's row there and reads back one token.
+Counters ``serving.tokens_greedy_device`` and
+``serving.tokens_sampled_row`` count the tokens each path takes.
 
 A slot holds whatever decode state the model's layers keep: a KV cache
 for attention layers, and for Mamba-2 layers (``ssm``) the recurrent
@@ -53,7 +58,12 @@ def _slot_step_for(model: Model):
     loop-invariant operands of default-precision matmuls, and the TPU
     compiler converts each whole one to bfloat16 ahead of the loop on
     every step; unrolled, each layer's slice is converted inside its
-    matmul."""
+    matmul.
+
+    Inputs ``(params, cache, tokens (n, 1) int32, positions (n,) int32)``;
+    returns ``(next_tokens, logits, new_cache)``: each slot's greedy token
+    as int32 ``(n,)`` (``jnp.argmax``, the first of equal maxima, as
+    ``np.argmax``) and its logits row ``(n, V)``."""
     step = getattr(model, "_batcher_slot_step", None)
     if step is not None:
         return step
@@ -61,12 +71,26 @@ def _slot_step_for(model: Model):
     def one_slot_step(params, cache, token, pos):
         logits, new_cache = model.serve_step(params, cache, token[None, :],
                                              pos, scan_unroll=True)
-        return logits[0], new_cache
+        return logits[0, 0], new_cache
 
-    step = jax.jit(jax.vmap(one_slot_step, in_axes=(None, 0, 0, 0)),
-                   donate_argnums=(1,))
+    def slot_step(params, cache, tokens, positions):
+        logits, new_cache = jax.vmap(one_slot_step, in_axes=(None, 0, 0, 0))(
+            params, cache, tokens, positions)
+        next_tokens = jnp.argmax(logits[:, :model.cfg.vocab_size],
+                                 axis=-1).astype(jnp.int32)
+        return next_tokens, logits, new_cache
+
+    step = jax.jit(slot_step, donate_argnums=(1,))
     model._batcher_slot_step = step
     return step
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _sample_row(key, logits, s, temperature, vocab):
+    """A token drawn from slot ``s``'s row of ``logits`` (its first
+    ``vocab`` entries) at ``temperature``, on the device: only the token
+    leaves it."""
+    return jax.random.categorical(key, logits[s, :vocab] / temperature)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -142,6 +166,8 @@ class ContinuousBatcher:
         # wait in the queue
         self.slot_submitted = np.zeros(n_slots)
         self.slot_steps = np.zeros(n_slots, dtype=np.int64)
+        # the last step's greedy token of each slot, copied to the host
+        self.next_tokens = np.zeros(n_slots, dtype=np.int32)
         self.stats = {"steps": 0, "slot_tokens": 0, "idle_slot_tokens": 0}
 
     # ------------------------------------------------------------------
@@ -181,7 +207,10 @@ class ContinuousBatcher:
         Each phase is a profiler span (``obs.spans.span``) inside
         ``serve.step``: ``serve.admit``, ``serve.dispatch``,
         ``serve.device_wait`` (the host waits for the step),
-        ``serve.logits_to_host`` (the copy alone) and ``serve.sample``."""
+        ``serve.logits_to_host`` (the step's result reaching the host: the
+        greedy tokens the device chose, ``n_slots`` int32; the logits stay
+        on the device) and ``serve.sample`` (each slot's token taken, and
+        the bookkeeping)."""
         with span("serve.step"):
             with span("serve.admit"):
                 self._try_fill_slots()
@@ -199,23 +228,25 @@ class ContinuousBatcher:
                     else:
                         tokens[s, 0] = 0
                 positions = jnp.asarray(self.slot_pos.astype(np.int32))
-                logits, self.cache = self._step(self.params, self.cache,
-                                                jnp.asarray(tokens), positions)
-                last = logits[:, 0, :]
+                next_tokens, logits, self.cache = self._step(
+                    self.params, self.cache, jnp.asarray(tokens), positions)
                 self.stats["steps"] += 1
                 self.stats["slot_tokens"] += len(live)
                 self.stats["idle_slot_tokens"] += self.n_slots - len(live)
             with span("serve.device_wait"):
-                jax.block_until_ready(last)
+                jax.block_until_ready(next_tokens)
             with span("serve.logits_to_host"):
-                logits_np = np.asarray(last)
+                self.next_tokens = np.asarray(next_tokens)
             with span("serve.sample"):
-                return self._sample(live, logits_np)
+                return self._sample(live, logits)
 
-    def _sample(self, live: List[int], logits_np: np.ndarray) -> int:
-        """Take each live slot's next token from the step's logits, and
-        finish the requests that are done; returns how many finished."""
-        done = 0
+    def _sample(self, live: List[int], logits: jax.Array) -> int:
+        """Take each live slot's next token, and finish the requests that
+        are done; returns how many finished.  A greedy slot takes the token
+        the device chose (``self.next_tokens``); a slot with
+        ``temperature > 0`` samples from its row of the step's ``logits``
+        on the device."""
+        done = greedy = sampled = 0
         for s in live:
             req = self.slot_req[s]
             self.slot_pos[s] += 1
@@ -224,14 +255,14 @@ class ContinuousBatcher:
                 self.slot_pending[s].pop(0)
                 if self.slot_pending[s]:
                     continue  # still prefilling
-            # sample the next token from this step's logits
-            v = logits_np[s, : self.model.cfg.vocab_size]
             if req.temperature > 0:
                 self._key, sub = jax.random.split(self._key)
-                tok = int(jax.random.categorical(
-                    sub, jnp.asarray(v) / req.temperature))
+                tok = int(_sample_row(sub, logits, s, req.temperature,
+                                      self.model.cfg.vocab_size))
+                sampled += 1
             else:
-                tok = int(np.argmax(v))
+                tok = int(self.next_tokens[s])
+                greedy += 1
             self.slot_out[s].append(tok)
             finished = (len(self.slot_out[s]) >= req.max_new_tokens
                         or (req.eos_id is not None and tok == req.eos_id)
@@ -251,6 +282,8 @@ class ContinuousBatcher:
                 obs_metrics.counter("serving.requests_completed").inc()
                 self.slot_req[s] = None
                 done += 1
+        obs_metrics.counter("serving.tokens_greedy_device").inc(greedy)
+        obs_metrics.counter("serving.tokens_sampled_row").inc(sampled)
         return done
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[int, RequestResult]:

@@ -215,6 +215,55 @@ def test_reused_slot_matches_sequential(setup):
                                    rtol=1e-5, atol=1e-5, err_msg=str(i))
 
 
+def test_greedy_tokens_come_from_the_device_and_sampling_from_one_row(
+        setup, monkeypatch):
+    """A greedy and a ``temperature > 0`` request share the step: the
+    greedy one takes the token the device chose, as sequential decode
+    does; the other draws from its own slot's logits row on the device,
+    as ``jax.random.categorical`` over that row at that temperature does.
+    Each path's counter counts its tokens, and no step brings an array of
+    a vocabulary's size to the host."""
+    cfg, model, params = setup
+    prompts, k, temp = [[5, 6, 7], [8, 9]], 6, 0.7
+    want = _sequential_decode(model, params, prompts[0], k)
+    batcher = ContinuousBatcher(model, params, n_slots=2, max_seq=64, seed=3)
+    batcher.submit(Request(rid=0, prompt=prompts[0], max_new_tokens=k))
+    batcher.submit(Request(rid=1, prompt=prompts[1], max_new_tokens=k,
+                           temperature=temp))
+    greedy = obs_metrics.counter("serving.tokens_greedy_device")
+    sampled = obs_metrics.counter("serving.tokens_sampled_row")
+    greedy0, sampled0 = greedy.value, sampled.value
+    pulled = []
+
+    def recorded(fn):
+        def wrapped(x, *args, **kwargs):
+            pulled.extend(leaf.shape for leaf in jax.tree.leaves(x)
+                          if isinstance(leaf, jax.Array))
+            return fn(x, *args, **kwargs)
+        return wrapped
+
+    for mod, name in ((np, "asarray"), (np, "array"), (jax, "device_get")):
+        monkeypatch.setattr(mod, name, recorded(getattr(mod, name)))
+    results = batcher.run_until_drained()
+    monkeypatch.undo()
+
+    assert results[0].tokens == want
+    assert greedy.value - greedy0 == k
+    assert sampled.value - sampled0 == k
+    assert (2,) in pulled  # the step's tokens
+    assert all(int(np.prod(shape)) < cfg.vocab_size for shape in pulled), \
+        pulled
+
+    served = results[1].tokens
+    rows = _sequential_logits(model, params, prompts[1] + served[:-1])
+    key, drawn = jax.random.PRNGKey(3), []
+    for row in rows[len(prompts[1]) - 1:]:
+        key, sub = jax.random.split(key)
+        drawn.append(int(jax.random.categorical(
+            sub, jnp.asarray(row[:cfg.vocab_size]) / temp)))
+    assert served == drawn
+
+
 def test_step_donates_the_cache(setup):
     """The slot reset and the slot step update the batcher's cache in
     place: the tree it held before a step is deleted after it."""

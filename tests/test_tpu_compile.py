@@ -149,20 +149,54 @@ def _hlo_ops(text):
             for m in map(pat.match, text.splitlines()) if m]
 
 
-def test_qwen2_slot_step_updates_the_cache_in_place_on_v5e(one_chip):
+def _compile_slot_step(one_chip, model, params, cache, n):
+    """The batcher's slot step for ``n`` slots, compiled, and the bytes of
+    the cache it is given."""
+    from repro.serving.batcher import _slot_step_for
+
+    compiled = _slot_step_for(model).lower(
+        params, cache, _on(one_chip, jax.ShapeDtypeStruct((n, 1), jnp.int32)),
+        _on(one_chip, jax.ShapeDtypeStruct((n,), jnp.int32))).compile()
+    return compiled, sum(s.size * s.dtype.itemsize
+                         for s in jax.tree.leaves(cache))
+
+
+@pytest.fixture(scope="module")
+def qwen2_slot_step(one_chip):
+    """qwen2-0.5b's slot step at 64 slots x 2048, compiled once."""
+    model, params, cache, _ = _qwen2_slot_cache(one_chip, 64, 2048)
+    return _compile_slot_step(one_chip, model, params, cache, 64)
+
+
+@pytest.fixture(scope="module")
+def granite_slot_step(one_chip):
+    """granite-4.0-h-micro's slot step, cut to two periods (18 Mamba-2 and
+    2 attention layers) at published widths, 64 slots x 2048, compiled
+    once."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.api import Model
+    from repro.models.params import abstract_params
+
+    full = get_config("granite-4.0-h-micro")
+    cfg = dataclasses.replace(full, n_layers=20, layer_types=full.layer_types[:20])
+    model = Model.for_config(cfg)
+    one = abstract_params(model.init_cache_desc(batch=1, max_seq=2048))
+    cache = _on(one_chip, jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((64,) + s.shape, s.dtype), one))
+    return _compile_slot_step(one_chip, model,
+                              _on(one_chip, model.abstract_params()), cache, 64)
+
+
+def test_qwen2_slot_step_updates_the_cache_in_place_on_v5e(qwen2_slot_step):
     """The batcher's slot step at 64 slots x 2048 aliases the donated
     cache and neither copies, converts, transposes nor restacks it: no
     copy, convert, transpose or fusion yields a whole-cache or
     whole-layer shape, in any element type."""
-    from repro.serving.batcher import _slot_step_for
-
     n, span = 64, 2048
-    model, params, cache, _ = _qwen2_slot_cache(one_chip, n, span)
-    compiled = _slot_step_for(model).lower(
-        params, cache, _on(one_chip, jax.ShapeDtypeStruct((n, 1), jnp.int32)),
-        _on(one_chip, jax.ShapeDtypeStruct((n,), jnp.int32))).compile()
+    compiled, cache_bytes = qwen2_slot_step
     mem = compiled.memory_analysis()
-    cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes <= 2.5e9, mem.temp_size_in_bytes
     layers, kv, hd = 24, 2, 64
@@ -193,33 +227,17 @@ def test_qwen2_slot_reset_updates_one_slot_in_place_on_v5e(one_chip):
     assert not copies, copies
 
 
-def test_granite_slot_step_updates_the_state_in_place_on_v5e(one_chip):
+def test_granite_slot_step_updates_the_state_in_place_on_v5e(
+        granite_slot_step):
     """granite-4.0-h-micro's slot step, cut to two periods (18 Mamba-2 and
     2 attention layers) at published widths, 64 slots x 2048, aliases the
     donated cache, and rewrites each layer's SSM state in place: the only
     ops whose result is a group's whole state are the 18 layer updates
     (fusions), and no op yields one layer's state for all slots (a copy
     of it before its update)."""
-    import dataclasses
-
-    from repro.configs import get_config
-    from repro.models.api import Model
-    from repro.models.params import abstract_params
-    from repro.serving.batcher import _slot_step_for
-
-    full = get_config("granite-4.0-h-micro")
-    cfg = dataclasses.replace(full, n_layers=20, layer_types=full.layer_types[:20])
-    n, span = 64, 2048
-    model = Model.for_config(cfg)
-    one = abstract_params(model.init_cache_desc(batch=1, max_seq=span))
-    cache = _on(one_chip, jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((n,) + s.shape, s.dtype), one))
-    compiled = _slot_step_for(model).lower(
-        _on(one_chip, model.abstract_params()), cache,
-        _on(one_chip, jax.ShapeDtypeStruct((n, 1), jnp.int32)),
-        _on(one_chip, jax.ShapeDtypeStruct((n,), jnp.int32))).compile()
+    n = 64
+    compiled, cache_bytes = granite_slot_step
     mem = compiled.memory_analysis()
-    cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= cache_bytes
     state = "64,64,128"   # a layer's state: 64 heads of 64 x state 128
     groups = {f"{n},{g},1,{state}" for g in (5, 9, 4)}
@@ -231,3 +249,23 @@ def test_granite_slot_step_updates_the_state_in_place_on_v5e(one_chip):
         [f"{n},5,1,{state}"] * 5 + [f"{n},9,1,{state}"] * 9
         + [f"{n},4,1,{state}"] * 4), ops
     assert all(op == "fusion" and dims in groups for op, dims in ops), ops
+
+
+@pytest.mark.parametrize("model, vocab", [("qwen2", 151936),
+                                          ("granite", 100352)])
+def test_slot_step_returns_greedy_tokens_not_a_logits_copy_on_v5e(
+        request, model, vocab):
+    """The slot step at 64 slots returns each slot's greedy token as
+    ``s32[64]`` beside the logits and the donated cache, and no ``copy``
+    yields the logits, squeezed or not: no relayout of the whole row.  The
+    logits reach their output buffer whole once, by the compiler's
+    asynchronous ``copy-start``/``copy-done`` out of the fusion that
+    computed them, in the same layout."""
+    compiled, cache_bytes = request.getfixturevalue(f"{model}_slot_step")
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    tokens, logits, _ = compiled.out_info
+    assert (tokens.shape, tokens.dtype) == ((64,), jnp.int32)
+    assert (logits.shape, logits.dtype) == ((64, vocab), jnp.float32)
+    copies = [(op, ty, dims) for op, ty, dims in _hlo_ops(compiled.as_text())
+              if op == "copy" and dims in (f"64,{vocab}", f"64,1,{vocab}")]
+    assert not copies, copies
