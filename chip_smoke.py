@@ -7,24 +7,20 @@
 With no option the script runs these phases in one process, in order:
 
   device  JAX must report a TPU; there is no CPU fallback.
-  train   repro.launch.train.train("smollm-360m", engine="jit") for a few
+  train   repro.launch.train.train("smollm-360m") for a few
           steps at published widths (32 layers, d_model 960, vocab 49152,
           batch 8x256): every loss finite, the first near ln(vocab), the
           parameters on the TPU.
   kernels the compiled Pallas kernels the registry sends to the chip
           (flash_decode, ssd_scan) at published widths, against their
           references in repro.kernels.ref.
-  serve   repro.launch.serve.serve("qwen2-0.5b", engine="jit") on a few
+  serve   repro.launch.serve.serve("qwen2-0.5b") on a few
           requests: token ids in the vocabulary, and the last prompt
           position's logits, produced through the cache by serve_step,
           equal to a plain full forward over the same prompt.  Once at
           JAX's default matmul precision, the program serve.main runs,
           and once at float32, where the two paths agree to summation
           order.
-  graph   the same training run through the Session engine with fast
-          numerics and the parity guard on: the guard checked the first
-          step, demoted nothing and raised no RuntimeWarning, and the
-          losses equal the train phase's.
 
 With --four-chips it runs only the full-width smollm-360m train step
 sharded over a (data=2, model=2) mesh and the same step, init and batch
@@ -44,7 +40,6 @@ import math
 import os
 import statistics
 import sys
-import warnings
 
 SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 
@@ -68,8 +63,6 @@ LOGITS_TOL = {"default": 3e-2, "float32": 1e-3}
 # at the default precision.  A layout or recurrence fault gives O(1).
 # Readings in PERF.md.
 KERNEL_TOL = {"flash_decode": 8e-3, "ssd_scan": 2e-2}
-# graph engine vs jit engine: one step graph, same init and batches
-GRAPH_LOSS_TOL = 1e-3
 # sharded (data=2, model=2) vs one device.  First-step loss: partial sums
 # of the sharded contractions are added in another order.  After one
 # step, per leaf, by numerics.update_drift: the gradient (AdamW's first
@@ -105,13 +98,13 @@ def phase_device() -> dict:
 
 
 def phase_train(*, smoke: bool = False, steps: int = 4, batch: int = 8,
-                seq: int = 256, platform: str = PLATFORM) -> list:
+                seq: int = 256, platform: str = PLATFORM) -> None:
     import jax
 
     from repro.configs import get_config
     from repro.launch.train import train
 
-    res = train(TRAIN_ARCH, smoke=smoke, engine="jit", steps=steps,
+    res = train(TRAIN_ARCH, smoke=smoke, steps=steps,
                 batch=batch, seq=seq, log_every=steps)
     losses = res["losses"]
     check(len(losses) == steps and all(math.isfinite(x) for x in losses),
@@ -129,7 +122,6 @@ def phase_train(*, smoke: bool = False, steps: int = 4, batch: int = 8,
     print(f"[train] first step (compile) {secs[0]:.3f} s", flush=True)
     print(f"[train] steady step {statistics.median(secs[1:]):.4f} s "
           f"(median of {len(secs) - 1})", flush=True)
-    return losses
 
 
 def phase_kernels(*, smoke: bool = False, seed: int = 0) -> None:
@@ -208,7 +200,7 @@ def phase_serve(*, smoke: bool = False, batch: int = 4, prompt_len: int = 16,
     for precision in LOGITS_TOL:
         with (contextlib.nullcontext() if precision == "default"
               else jax.default_matmul_precision(precision)):
-            res = serve(SERVE_ARCH, smoke=smoke, engine="jit", batch=batch,
+            res = serve(SERVE_ARCH, smoke=smoke, batch=batch,
                         prompt_len=prompt_len, gen=gen, seed=seed)
             want = np.asarray(jax.jit(full_forward)(
                 params, res["prompts"]))[:, 0, :V]
@@ -232,42 +224,6 @@ def phase_serve(*, smoke: bool = False, batch: int = 4, prompt_len: int = 16,
         print(f"[serve] {precision} precision: prefill {res['prefill_s']:.3f}"
               f" s, decode {res['decode_s']:.3f} s for {batch}x{gen} tokens",
               flush=True)
-
-
-def phase_graph(jit_losses: list, *, smoke: bool = False, steps: int = 3,
-                batch: int = 8, seq: int = 256) -> None:
-    from repro.launch.train import train
-    from repro.obs import metrics as obs_metrics
-
-    checks = obs_metrics.counter("numerics.guard_checks")
-    demotions = obs_metrics.counter("numerics.guard_demotions")
-    checks0, demotions0 = checks.value, demotions.value
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        res = train(TRAIN_ARCH, smoke=smoke, engine="graph", numerics="fast",
-                    steps=steps, batch=batch, seq=seq, log_every=steps)
-    runtime = [w for w in caught if issubclass(w.category, RuntimeWarning)]
-    for w in runtime:
-        print(f"[graph] RuntimeWarning: {w.message}", flush=True)
-    print(f"[graph] parity guard: {checks.value - checks0} check(s), rel "
-          f"drift {obs_metrics.gauge('numerics.guard_rel_drift').value}, "
-          f"update drift "
-          f"{obs_metrics.gauge('numerics.guard_update_drift').value}",
-          flush=True)
-    check(not runtime, f"{len(runtime)} RuntimeWarning(s) in the graph engine")
-    check(checks.value > checks0, "the parity guard checked no step")
-    check(demotions.value == demotions0,
-          f"{demotions.value - demotions0} Executable(s) demoted to strict")
-    losses = res["losses"]
-    drift = max(abs(a - b) for a, b in zip(losses, jit_losses))
-    print(f"[graph] losses {[round(x, 4) for x in losses]}; max |diff| vs "
-          f"jit {drift:.3e} (limit {GRAPH_LOSS_TOL})", flush=True)
-    check(all(math.isfinite(x) for x in losses), "graph losses not finite")
-    check(drift <= GRAPH_LOSS_TOL,
-          f"graph-engine losses differ from the jit engine's by {drift:.3e}")
-    secs = res["step_seconds"]
-    print(f"[graph] first step (guarded) {secs[0]:.3f} s, steady step "
-          f"{statistics.median(secs[1:]):.4f} s", flush=True)
 
 
 def phase_mesh(*, smoke: bool = False, steps: int = 3, batch: int = 8,
@@ -390,10 +346,9 @@ def main(argv=None) -> int:
         if args.four_chips:
             phase_mesh()
         else:
-            jit_losses = phase_train()
+            phase_train()
             phase_kernels()
             phase_serve()
-            phase_graph(jit_losses[:3])
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -111,18 +111,24 @@ def factory_wire_train():
     ])
 
 
-def factory_eager_train():
+def factory_train():
+    """The graph ``build_train_step`` lowers, at llama3_8b smoke size."""
     from ..configs import get_config
-    from ..launch.steps import build_eager_train_step
-    from ..models.api import Shape
-    return build_eager_train_step(get_config("llama3_8b", smoke=True),
-                                  Shape("lint", 64, 2, "train"))
+    from ..launch import steps
+    from ..models.api import Model, Shape
+    cfg = get_config("llama3_8b", smoke=True)
+    shape = Shape("lint", 64, 2, "train")
+    model = Model.for_config(cfg)
+    loss_kw = steps.loss_kwargs(model, steps.step_hparams(cfg, shape, 1))
+    return steps._train_graph(list(model.batch_desc(shape)), cfg, 1, loss_kw,
+                              lr=3e-4, n_micro=1)
 
 
-def factory_eager_serve():
+def factory_serve():
+    """The graph ``build_serve_step`` lowers, at llama3_8b smoke size."""
     from ..configs import get_config
-    from ..launch.steps import build_eager_serve_step
-    return build_eager_serve_step(get_config("llama3_8b", smoke=True))
+    from ..launch import steps
+    return steps._serve_graph(get_config("llama3_8b", smoke=True), 1, {})
 
 
 def _example(fname: str, qual: str) -> Optional[str]:
@@ -135,8 +141,8 @@ def _example(fname: str, qual: str) -> Optional[str]:
 def suite_specs() -> List[Tuple[str, str]]:
     specs = [
         ("launch:wire_train_2task", "repro.analysis.lint:factory_wire_train"),
-        ("launch:eager_train_smoke", "repro.analysis.lint:factory_eager_train"),
-        ("launch:eager_serve_smoke", "repro.analysis.lint:factory_eager_serve"),
+        ("launch:train_smoke", "repro.analysis.lint:factory_train"),
+        ("launch:serve_smoke", "repro.analysis.lint:factory_serve"),
     ]
     qs = _example("quickstart.py", "build_graph")
     if qs:

@@ -1,10 +1,13 @@
 """Shared CLI surface for the launch tools (DESIGN.md §15).
 
-``train`` and ``serve`` expose the same engine/numerics/cluster flags;
-this module defines them once so the two parsers cannot drift, and turns
-parsed args into a :class:`~repro.core.options.SessionOptions` in one
-place — the options object then applies the documented resolution order
-(explicit > ``REPRO_*`` env > default) itself.
+``train`` and ``serve`` share the model, cluster and observability
+flags; ``train`` alone adds the fused-region flags its replicated
+Session reads.  This module defines them once so the parsers cannot
+drift, and turns parsed args into a
+:class:`~repro.core.options.SessionOptions` in one place — the options
+object then applies the documented resolution order (explicit >
+``REPRO_*`` env > default) itself.  The LM steps themselves are always
+lowered and jitted.
 """
 from __future__ import annotations
 
@@ -46,19 +49,15 @@ def add_model_options(ap: argparse.ArgumentParser, *, arch: str
     return ap
 
 
-def add_engine_options(ap: argparse.ArgumentParser,
-                       *, numerics_default: str = "fast"
+def add_fusion_options(ap: argparse.ArgumentParser
                        ) -> argparse.ArgumentParser:
-    """--engine / --numerics / --backend: how a step executes locally."""
-    ap.add_argument("--engine", choices=("jit", "graph"), default="jit",
-                    help="jit: lowered+jitted step; graph: eager Session.run "
-                         "through the cached Executable (DESIGN.md §5)")
-    ap.add_argument("--numerics", choices=("fast", "strict"),
-                    default=numerics_default,
-                    help="graph-engine fused-region numerics (DESIGN.md §9): "
-                         "fast compiles regions at full XLA optimization "
-                         "under the CI-enforced tolerance contract; strict "
-                         "restores fused==unfused bit-parity")
+    """--numerics / --backend: how a Session's fused regions execute."""
+    ap.add_argument("--numerics", choices=("fast", "strict"), default="fast",
+                    help="fused-region numerics of the replicated Session "
+                         "(DESIGN.md §9): fast compiles regions at full XLA "
+                         "optimization under the CI-enforced tolerance "
+                         "contract; strict restores fused==unfused "
+                         "bit-parity")
     ap.add_argument("--backend", default=None, metavar="NAME",
                     help="kernel backend for fused regions (e.g. pallas; "
                          "DESIGN.md §12) — default resolves "

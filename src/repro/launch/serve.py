@@ -1,5 +1,5 @@
-"""Batched serving driver: prefill + decode with the cache-as-Variable
-graph (deliverable (b): serving example).
+"""Batched serving driver: prefill + decode through one jitted decode step
+(deliverable (b): serving example).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \\
       --batch 4 --prompt-len 16 --gen 32
@@ -16,12 +16,11 @@ import numpy as np
 
 from ..configs import get_config
 from ..core.options import SessionOptions
-from ..models.api import Model, Shape
+from ..models.api import Model
 from ..models.params import init_params
 from ..obs import metrics as obs_metrics
-from .cli import (add_cluster_options, add_engine_options, add_model_options,
-                  add_obs_options, enable_compile_cache)
-from .steps import build_serve_step, build_eager_serve_step
+from .cli import (add_cluster_options, add_model_options, add_obs_options,
+                  enable_compile_cache)
 
 
 def print_metrics(label: str = "serve") -> None:
@@ -41,15 +40,11 @@ def print_metrics(label: str = "serve") -> None:
 
 def serve(arch: str = "qwen2-0.5b", *, smoke: bool = False, batch: int = 4,
           prompt_len: int = 16, gen: int = 32, max_seq: int = 128,
-          seed: int = 0, temperature: float = 0.0,
-          engine: str = "jit", numerics: str = "fast",
-          backend: Optional[str] = None) -> Dict[str, Any]:
-    """``engine="jit"`` jits one decode step; ``engine="graph"`` drives the
-    decode loop through ``Session.run`` with the KV cache as a Variable —
-    every token re-runs one cached Executable (DESIGN.md §5).  The graph
-    engine defaults to ``numerics="fast"`` (the decode Call + cache Assign
-    fuse into one region at full XLA optimization, §9 tolerance contract);
-    ``numerics="strict"`` restores bit-parity with unfused execution.
+          seed: int = 0, temperature: float = 0.0) -> Dict[str, Any]:
+    """Prefill and decode a batch of random prompts with one jitted decode
+    step, the cache donated.  This loop stays beside the batcher
+    (``repro.serving``) because it alone fills an encoder-decoder's cross
+    cache.
 
     Returns the ``generated`` token ids, the ``prompts`` and
     ``prompt_logits`` — the logits at the last prompt position, as the
@@ -74,33 +69,18 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = False, batch: int = 4,
         ck, cv = encdec.build_cross_cache(cfg, model.plan, params, enc_out)
         cache["cross_k"], cache["cross_v"] = ck, cv
 
-    eb = None
-    if engine == "graph":
-        eb = build_eager_serve_step(cfg, numerics=numerics,
-                                    options=SessionOptions(backend=backend))
-        eb.session.set_variable("params", params)
-        eb.session.set_variable("cache", cache)
-
-        def step(c, tk, t):
-            # the cache lives in the Session's "cache" Variable; the cached
-            # Executable's Assign node updates it in place each token
-            logits = eb.step({"tokens": tk.astype(jnp.int32), "pos": t})
-            return logits, c
-    else:
-        # params go in as an argument: closed over, they would be baked
-        # into the executable as constants (2.5 GB for qwen2-0.5b); the
-        # cache is donated, so each token's k/v are written in place
-        decode = jax.jit(model.serve_step, donate_argnums=(1,))
-
-        def step(c, tk, t):
-            return decode(params, c, tk, t)
+    # params go in as an argument: closed over, they would be baked into
+    # the executable as constants (2.5 GB for qwen2-0.5b); the cache is
+    # donated, so each token's k/v are written in place
+    decode = jax.jit(model.serve_step, donate_argnums=(1,))
 
     # --- prefill: feed prompt tokens one step at a time (the cache fills);
     # production prefill lowers the batched forward (launch/steps.py).
     t0 = time.time()
     logits = None
     for t in range(prompt_len):
-        logits, cache = step(cache, prompts[:, t:t + 1], jnp.array(t))
+        logits, cache = decode(params, cache, prompts[:, t:t + 1],
+                               jnp.array(t))
     prefill_s = time.time() - t0
     prompt_logits = logits
 
@@ -111,7 +91,8 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = False, batch: int = 4,
     t0 = time.time()
     for t in range(prompt_len, prompt_len + gen):
         out_tokens.append(np.asarray(tok))
-        logits, cache = step(cache, tok.astype(jnp.int32), jnp.array(t))
+        logits, cache = decode(params, cache, tok.astype(jnp.int32),
+                               jnp.array(t))
         if temperature > 0:
             key, sub = jax.random.split(key)
             tok = jax.random.categorical(
@@ -122,16 +103,12 @@ def serve(arch: str = "qwen2-0.5b", *, smoke: bool = False, batch: int = 4,
 
     gen_arr = np.concatenate(out_tokens, axis=1)
     tput = batch * gen / decode_s if decode_s > 0 else float("inf")
-    print(f"[serve] arch={cfg.arch_id} engine={engine}"
-          f"{'/' + numerics if engine == 'graph' else ''} batch={batch} "
+    print(f"[serve] arch={cfg.arch_id} batch={batch} "
           f"prefill {prefill_s:.2f}s "
           f"decode {decode_s:.2f}s ({tput:.1f} tok/s)")
-    res = {"generated": gen_arr, "prompts": prompts,
-           "prompt_logits": prompt_logits, "prefill_s": prefill_s,
-           "decode_s": decode_s, "tokens_per_s": tput}
-    if eb is not None:
-        res["executable_cache"] = eb.session.cache_stats
-    return res
+    return {"generated": gen_arr, "prompts": prompts,
+            "prompt_logits": prompt_logits, "prefill_s": prefill_s,
+            "decode_s": decode_s, "tokens_per_s": tput}
 
 
 def serve_cluster(cluster: str, *, batch: int = 32, requests: int = 100,
@@ -194,7 +171,6 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=32)
-    add_engine_options(ap)
     add_cluster_options(ap)
     add_obs_options(ap)
     ap.add_argument("--requests", type=int, default=100,
@@ -207,8 +183,7 @@ def main(argv=None) -> int:
                       metrics_every=args.metrics_every)
         return 0
     res = serve(args.arch, smoke=args.smoke, batch=args.batch,
-                prompt_len=args.prompt_len, gen=args.gen, engine=args.engine,
-                numerics=args.numerics, backend=args.backend)
+                prompt_len=args.prompt_len, gen=args.gen)
     print("[serve] sample token ids:", res["generated"][0][:16].tolist())
     if args.metrics_every:
         print_metrics()

@@ -18,8 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core import (GraphBuilder, Session, SessionOptions, compile_subgraph,
-                    gradients)
+from ..core import GraphBuilder, Session, compile_subgraph, gradients
 from ..core import numerics
 from ..models.api import Model, Shape, SHAPES
 from ..models.config import ModelConfig
@@ -42,32 +41,6 @@ class StepBundle:
     model: Model
     kind: str
     graph_nodes: int = 0
-
-
-@dataclasses.dataclass
-class EagerStepBundle:
-    """A step driven through ``Session.run`` (the §2 eager path).
-
-    ``step`` is bound to the Session's cached Executable for its run
-    signature (DESIGN.md §5): the first call pays prune/place/partition/
-    schedule + executor static analysis, every subsequent call only
-    allocates per-run executor state.  Variables (params/opt/cache) live
-    in the Session's variable store — set them with
-    ``bundle.session.set_variable`` before the first step.
-    """
-
-    session: Session
-    step: Callable[[Dict[str, Any]], Any]  # feeds by name -> primary output
-    model: Model
-    feed_names: Tuple[str, ...]
-    kind: str
-    graph_nodes: int = 0
-
-    def variables(self) -> Dict[str, Any]:
-        """Snapshot the step's Variables (e.g. for checkpointing)."""
-        return {name: self.session.variable_value(name)
-                for name, node in self.session.graph.nodes.items()
-                if node.op == "Variable"}
 
 
 def _named(mesh: Optional[Mesh], spec_tree):
@@ -187,13 +160,24 @@ def lm_serve_factory(cfg: ModelConfig, shard: int, serve_kw):
     return serve
 
 
+def loss_kwargs(model: Model, hp: Dict[str, Any]) -> Dict[str, Any]:
+    """The loss kernel's keyword arguments under step hyperparameters
+    ``hp`` (see ``step_hparams``)."""
+    loss_kw = dict(q_chunk=hp["q_chunk"], loss_chunk=hp["loss_chunk"],
+                   compute_dtype=hp["compute_dtype"],
+                   scan_unroll=hp["scan_unroll"])
+    if not model.is_encdec:
+        loss_kw["n_token_groups"] = hp["n_token_groups"]
+    return loss_kw
+
+
 def _train_graph(feed_names, cfg: ModelConfig, shard: int, loss_kw,
                  lr: float, n_micro: int):
     """The training step AS A repro.core GRAPH: loss Call node, §4.1
     ``gradients()`` backward extension, AdamW update + Assign nodes —
-    shared by the lowered (JIT) and eager (Session.run) paths.  Every
-    Call is declared through a wire-shippable factory (§15), so the same
-    graph also registers on a worker pool."""
+    what ``build_train_step`` lowers.  Every Call is declared through a
+    wire-shippable factory (§15), so the same graph also registers on a
+    worker pool."""
     b = GraphBuilder()
     v_params = b.variable("params")
     v_opt = b.variable("opt")
@@ -221,6 +205,21 @@ def _train_graph(feed_names, cfg: ModelConfig, shard: int, loss_kw,
     return b, loss_node, a1, a2, feed_nodes
 
 
+def _serve_graph(cfg: ModelConfig, shard: int, serve_kw):
+    """One-token decode AS A repro.core GRAPH — what ``build_serve_step``
+    lowers: the decode Call reads the ``cache`` Variable and an Assign
+    writes the updated cache back."""
+    b = GraphBuilder()
+    v_params = b.variable("params")
+    v_cache = b.variable("cache")
+    t_ph = b.placeholder("tokens")
+    p_ph = b.placeholder("pos")
+    out = b.call_factory(LM_SERVE_FACTORY, [v_params, v_cache, t_ph, p_ph],
+                         args=(cfg, shard, serve_kw), name="serve", n_out=2)
+    a_cache = b.assign(v_cache, out.output(1))
+    return b, out, a_cache, t_ph, p_ph
+
+
 def build_train_step(
     cfg: ModelConfig,
     shape: Shape,
@@ -229,73 +228,28 @@ def build_train_step(
     *,
     lr: float = 3e-4,
     hparam_overrides: Optional[Dict[str, Any]] = None,
-    via_graph: bool = True,
 ) -> StepBundle:
     shard = mesh.shape["model"] if mesh is not None else 1
     n_groups = mesh_mod.batch_shard_size(mesh) if mesh is not None else 1
     model = Model.for_config(cfg, shard)
     hp = step_hparams(cfg, shape, n_groups)
     hp.update(hparam_overrides or {})
-    loss_kw = dict(q_chunk=hp["q_chunk"], loss_chunk=hp["loss_chunk"],
-                   compute_dtype=hp["compute_dtype"],
-                   scan_unroll=hp["scan_unroll"])
-    if not model.is_encdec:
-        loss_kw["n_token_groups"] = hp["n_token_groups"]
-
-    def loss_of(params, batch):
-        return model.loss_fn(params, batch, **loss_kw)
-
-    def update_of(params, grads, opt):
-        return adamw_update(params, grads, opt, lr=lr)
-
+    loss_kw = loss_kwargs(model, hp)
     batch_desc = model.batch_desc(shape)
     feed_names = list(batch_desc)
     n_micro = int(hp.get("microbatch", 1))
 
-    def loss_and_grad_of(params, batch):
-        """Gradient accumulation over n_micro microbatches (memory lever:
-        stored activations scale with B/n_micro, grads accumulate fp32)."""
-        if n_micro <= 1:
-            return jax.value_and_grad(loss_of)(params, batch)
-        B = batch["tokens"].shape[0]
-        assert B % n_micro == 0, (B, n_micro)
-        mb = {k: v.reshape((n_micro, B // n_micro) + v.shape[1:])
-              for k, v in batch.items()}
+    b, loss_node, a1, a2, feed_nodes = _train_graph(
+        feed_names, cfg, shard, loss_kw, lr, n_micro)
+    lowered = compile_subgraph(
+        Session(b.graph), [loss_node.ref],
+        [feed_nodes[n].ref for n in feed_names],
+        extra_updates=[a1.name, a2.name])
 
-        def body(carry, mbatch):
-            tot_loss, acc = carry
-            l, g = jax.value_and_grad(loss_of)(params, mbatch)
-            acc = jax.tree.map(
-                lambda a, gi: a + gi.astype(jnp.float32) / n_micro, acc, g)
-            return (tot_loss + l / n_micro, acc), None
-
-        zeros = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (loss_val, grads), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), zeros), mb)
-        return loss_val, grads
-
-    if via_graph:
-        b, loss_node, a1, a2, feed_nodes = _train_graph(
-            feed_names, cfg, shard, loss_kw, lr, n_micro)
-        sess = Session(b.graph)
-        lowered = compile_subgraph(
-            sess, [loss_node.ref], [feed_nodes[n].ref for n in feed_names],
-            extra_updates=[a1.name, a2.name])
-        n_nodes = lowered.n_nodes
-
-        def fn(feeds: Dict[str, Any], variables: Dict[str, Any]):
-            feed_vals = {_feed_key(n): feeds[n] for n in feed_names}
-            (loss_val,), new_vars = lowered.fn(feed_vals, variables)
-            return loss_val, new_vars
-    else:
-        n_nodes = 0
-
-        def fn(feeds: Dict[str, Any], variables: Dict[str, Any]):
-            params, opt = variables["params"], variables["opt"]
-            loss_val, grads = loss_and_grad_of(params, feeds)
-            new_params, new_opt = update_of(params, grads, opt)
-            return loss_val, {"params": new_params, "opt": new_opt}
+    def fn(feeds: Dict[str, Any], variables: Dict[str, Any]):
+        feed_vals = {_feed_key(n): feeds[n] for n in feed_names}
+        (loss_val,), new_vars = lowered.fn(feed_vals, variables)
+        return loss_val, new_vars
 
     # --- specs + shardings
     pdesc = model.describe_params()
@@ -324,7 +278,8 @@ def build_train_step(
                       feed_shardings=feed_shardings,
                       var_shardings=var_shardings,
                       out_shardings=out_shardings,
-                      model=model, kind="train", graph_nodes=n_nodes)
+                      model=model, kind="train",
+                      graph_nodes=lowered.n_nodes)
 
 
 def dataclasses_replace_optstate(pspec_tree, opt_template):
@@ -409,7 +364,6 @@ def build_serve_step(
     rules: Optional[Dict[str, Any]] = None,
     *,
     hparam_overrides: Optional[Dict[str, Any]] = None,
-    via_graph: bool = True,
 ) -> StepBundle:
     """One-token decode against a seq_len cache (Variable in the graph)."""
     shard = mesh.shape["model"] if mesh is not None else 1
@@ -425,36 +379,15 @@ def build_serve_step(
     if not model.is_encdec:
         serve_kw["n_token_groups"] = hp["n_token_groups"]
 
-    def serve_of(params, cache, tokens, pos):
-        return model.serve_step(params, cache, tokens, pos, **serve_kw)
+    b, out, a_cache, t_ph, p_ph = _serve_graph(cfg, shard, serve_kw)
+    lowered = compile_subgraph(Session(b.graph), [out.output(0)],
+                               [t_ph.ref, p_ph.ref],
+                               extra_updates=[a_cache.name])
 
-    if via_graph:
-        b = GraphBuilder()
-        v_params = b.variable("params")
-        v_cache = b.variable("cache")
-        t_ph = b.placeholder("tokens")
-        p_ph = b.placeholder("pos")
-        out = b.call_factory(LM_SERVE_FACTORY, [v_params, v_cache, t_ph, p_ph],
-                             args=(cfg, shard, serve_kw), name="serve",
-                             n_out=2)
-        a_cache = b.assign(v_cache, out.output(1))
-        sess = Session(b.graph)
-        lowered = compile_subgraph(sess, [out.output(0)],
-                                   [t_ph.ref, p_ph.ref],
-                                   extra_updates=[a_cache.name])
-        n_nodes = lowered.n_nodes
-
-        def fn(feeds: Dict[str, Any], variables: Dict[str, Any]):
-            feed_vals = {"tokens:0": feeds["tokens"], "pos:0": feeds["pos"]}
-            (logits,), new_vars = lowered.fn(feed_vals, variables)
-            return logits, new_vars
-    else:
-        n_nodes = 0
-
-        def fn(feeds, variables):
-            logits, new_cache = serve_of(variables["params"], variables["cache"],
-                                         feeds["tokens"], feeds["pos"])
-            return logits, {"cache": new_cache}
+    def fn(feeds: Dict[str, Any], variables: Dict[str, Any]):
+        feed_vals = {"tokens:0": feeds["tokens"], "pos:0": feeds["pos"]}
+        (logits,), new_vars = lowered.fn(feed_vals, variables)
+        return logits, new_vars
 
     pdesc = model.describe_params(serve_longctx=longctx)
     if hp.get("param_dtype") is not None:
@@ -501,89 +434,8 @@ def build_serve_step(
                       var_specs={"params": params_abs, "cache": cache_abs},
                       feed_shardings=feed_shardings,
                       var_shardings=var_shardings, out_shardings=out_shardings,
-                      model=model, kind="decode", graph_nodes=n_nodes)
-
-
-def build_eager_train_step(
-    cfg: ModelConfig,
-    shape: Shape,
-    *,
-    lr: float = 3e-4,
-    hparam_overrides: Optional[Dict[str, Any]] = None,
-    numerics: Optional[str] = None,
-    options: Optional[SessionOptions] = None,
-) -> EagerStepBundle:
-    """Train step for the eager multi-run path: the same graph as
-    ``build_train_step(via_graph=True)`` but *run*, not lowered — each call
-    re-enters ``Session.run`` and hits the cached Executable for the
-    (loss, train_op) signature (compile once, run many; DESIGN.md §5).
-    ``numerics`` selects the fused-region policy (DESIGN.md §9): the
-    train tool defaults the graph engine to "fast".  The graph is built
-    from §15 Call factories, so with ``options.cluster`` set the same
-    step registers and runs on a worker pool."""
-    model = Model.for_config(cfg)
-    hp = step_hparams(cfg, shape, 1)
-    hp.update(hparam_overrides or {})
-    loss_kw = dict(q_chunk=hp["q_chunk"], loss_chunk=hp["loss_chunk"],
-                   compute_dtype=hp["compute_dtype"],
-                   scan_unroll=hp["scan_unroll"])
-    if not model.is_encdec:
-        loss_kw["n_token_groups"] = hp["n_token_groups"]
-
-    feed_names = list(model.batch_desc(shape))
-    b, loss_node, a1, a2, feed_nodes = _train_graph(
-        feed_names, cfg, 1, loss_kw, lr, 1)
-    train_op = b.group([a1, a2], name="train_op")
-    opts = options or SessionOptions()
-    if numerics is not None:
-        opts = dataclasses.replace(opts, numerics=numerics)
-    sess = Session(b.graph, options=opts)
-    run = sess.make_callable([loss_node.ref, train_op.ref],
-                             [feed_nodes[n].ref for n in feed_names])
-
-    def step(feeds: Dict[str, Any]):
-        loss_val, _ = run(*[feeds[n] for n in feed_names])
-        return loss_val
-
-    return EagerStepBundle(session=sess, step=step, model=model,
-                           feed_names=tuple(feed_names), kind="train",
-                           graph_nodes=len(b.graph.nodes))
-
-
-def build_eager_serve_step(cfg: ModelConfig,
-                           numerics: Optional[str] = None,
-                           options: Optional[SessionOptions] = None
-                           ) -> EagerStepBundle:
-    """One-token decode as a Session graph: the KV cache is a Variable
-    updated by an Assign node, so the decode loop is exactly the paper's
-    steady-state serving shape — one cached Executable re-run per token.
-    Under ``numerics="fast"`` (the serve tool's graph-engine default) the
-    ``Call`` + cache Assign fuse into one jitted region (DESIGN.md §9).
-    The serve Call is factory-form (§15), so the graph is wire-shippable."""
-    model = Model.for_config(cfg)
-
-    b = GraphBuilder()
-    v_params = b.variable("params")
-    v_cache = b.variable("cache")
-    t_ph = b.placeholder("tokens")
-    p_ph = b.placeholder("pos")
-    out = b.call_factory(LM_SERVE_FACTORY, [v_params, v_cache, t_ph, p_ph],
-                         args=(cfg, 1, {}), name="serve", n_out=2)
-    a_cache = b.assign(v_cache, out.output(1))
-    opts = options or SessionOptions()
-    if numerics is not None:
-        opts = dataclasses.replace(opts, numerics=numerics)
-    sess = Session(b.graph, options=opts)
-    run = sess.make_callable([out.output(0), a_cache.ref],
-                             [t_ph.ref, p_ph.ref])
-
-    def step(feeds: Dict[str, Any]):
-        logits, _ = run(feeds["tokens"], feeds["pos"])
-        return logits
-
-    return EagerStepBundle(session=sess, step=step, model=model,
-                           feed_names=("tokens", "pos"), kind="decode",
-                           graph_nodes=len(b.graph.nodes))
+                      model=model, kind="decode",
+                      graph_nodes=lowered.n_nodes)
 
 
 @dataclasses.dataclass
@@ -717,11 +569,7 @@ def build_lm_replica_spec(cfg: ModelConfig, shape: Shape, *, lr: float = 1e-2,
     model = Model.for_config(cfg)
     hp = step_hparams(cfg, shape, 1)
     hp.update(hparam_overrides or {})
-    loss_kw = dict(q_chunk=hp["q_chunk"], loss_chunk=hp["loss_chunk"],
-                   compute_dtype=hp["compute_dtype"],
-                   scan_unroll=hp["scan_unroll"])
-    if not model.is_encdec:
-        loss_kw["n_token_groups"] = hp["n_token_groups"]
+    loss_kw = loss_kwargs(model, hp)
     feed_names = tuple(model.batch_desc(shape))
     params = init_params(model.describe_params(), jax.random.PRNGKey(seed))
     init = {"params": params, "opt": adamw_init(params)}

@@ -28,9 +28,9 @@ from ..models.api import Shape
 from ..models.params import init_params, count_params
 from ..obs import metrics as obs_metrics
 from ..optim import adamw_init
-from .cli import (add_cluster_options, add_engine_options, add_model_options,
+from .cli import (add_cluster_options, add_fusion_options, add_model_options,
                   add_obs_options, enable_compile_cache)
-from .steps import build_train_step, build_eager_train_step
+from .steps import build_train_step
 
 
 #: The step hyperparameters every training entry point runs with: f32
@@ -42,19 +42,12 @@ def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
           batch: int = 8, seq: int = 256, lr: float = 1e-3,
           ckpt_dir: Optional[str] = None, ckpt_every: int = 100,
           log_every: int = 10, seed: int = 0,
-          resume: bool = True, engine: str = "jit",
-          numerics: str = "fast",
-          backend: Optional[str] = None,
+          resume: bool = True,
           summary_dir: Optional[str] = None,
           metrics_every: int = 0) -> Dict[str, Any]:
-    """``engine="jit"`` lowers the step graph and jits it (§10);
-    ``engine="graph"`` drives the same graph through ``Session.run``, where
-    the steady-state loop re-runs one cached Executable per step
-    (compile once, run many; DESIGN.md §5).  The graph engine defaults to
-    ``numerics="fast"`` — fused regions (incl. matmuls/reductions) compile
-    at full XLA optimization under the §9 tolerance contract enforced by
-    the CI parity gate; ``numerics="strict"`` restores bit-parity with
-    unfused execution.
+    """Lowers the step graph (§10) and jits it with the training state
+    donated, so each step updates the parameters and optimizer state in
+    place.
 
     Returns the per-step ``losses``, the per-step wall seconds
     (``step_seconds``, each timed to the step's outputs being ready; the
@@ -62,32 +55,15 @@ def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
     (``variables``: ``params`` and ``opt``)."""
     cfg = get_config(arch, smoke=smoke)
     shape = Shape("custom", seq, batch, "train")
-    eb = None
-    if engine == "graph":
-        eb = build_eager_train_step(cfg, shape, lr=lr,
-                                    hparam_overrides=TRAIN_HPARAMS,
-                                    numerics=numerics,
-                                    options=SessionOptions(backend=backend))
-        model, graph_nodes = eb.model, eb.graph_nodes
-    else:
-        sb = build_train_step(cfg, shape, lr=lr,
-                              hparam_overrides=TRAIN_HPARAMS)
-        model, graph_nodes = sb.model, sb.graph_nodes
+    sb = build_train_step(cfg, shape, lr=lr, hparam_overrides=TRAIN_HPARAMS)
+    model = sb.model
     n_params = count_params(model.describe_params())
-    print(f"[train] arch={cfg.arch_id} engine={engine}"
-          f"{'/' + numerics if engine == 'graph' else ''} "
-          f"params={n_params/1e6:.1f}M "
-          f"batch={batch} seq={seq} graph_nodes={graph_nodes}")
+    print(f"[train] arch={cfg.arch_id} params={n_params/1e6:.1f}M "
+          f"batch={batch} seq={seq} graph_nodes={sb.graph_nodes}")
 
     params = init_params(model.describe_params(), jax.random.PRNGKey(seed))
     variables = {"params": params, "opt": adamw_init(params)}
-    if engine == "graph":
-        def step_fn(feeds, variables):
-            # params/opt live in the Session's variable store; the Assign
-            # nodes in the cached Executable update them in place.
-            return eb.step(feeds), variables
-    else:
-        step_fn = jax.jit(sb.fn, donate_argnums=(1,))
+    step_fn = jax.jit(sb.fn, donate_argnums=(1,))
 
     mgr = None
     start_step = 0
@@ -104,13 +80,6 @@ def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
             variables = rv
             start_step = int(mgr.latest_step())
             print(f"[train] resumed from step {start_step} (§3.3 recovery)")
-    if engine == "graph":
-        for name, value in variables.items():
-            eb.session.set_variable(name, value)
-        variables = {}  # the Session's store holds the only copy
-
-    def snapshot_variables() -> Dict[str, Any]:
-        return eb.variables() if engine == "graph" else variables
 
     ds = SyntheticLMDataset(cfg.vocab_size, seq, seed=seed)
     pipe = Prefetcher(batch_iterator(ds, batch, start_step), capacity=4).start()
@@ -141,7 +110,7 @@ def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
             writer.add(i + 1, "train/tokens_per_sec",
                        batch * seq / max(step_seconds[-1], 1e-9))
         if mgr and mgr.should_save(i + 1):
-            mgr.save(i + 1, {"variables": snapshot_variables()})
+            mgr.save(i + 1, {"variables": variables})
         if (i + 1) % log_every == 0:
             rate = (i + 1 - start_step) * batch * seq / (time.time() - t0)
             print(f"[train] step {i+1:5d} loss {float(loss):.4f} "
@@ -156,15 +125,10 @@ def train(arch: str = "smollm-360m", *, smoke: bool = False, steps: int = 200,
     if writer:
         writer.close()
     if mgr:
-        mgr.save(steps, {"variables": snapshot_variables()})
-    out: Dict[str, Any] = {"losses": losses,
-                           "final_loss": losses[-1] if losses else None,
-                           "params": n_params,
-                           "step_seconds": step_seconds,
-                           "variables": snapshot_variables()}
-    if engine == "graph":
-        out["executable_cache"] = eb.session.cache_stats
-    return out
+        mgr.save(steps, {"variables": variables})
+    return {"losses": losses, "final_loss": losses[-1] if losses else None,
+            "params": n_params, "step_seconds": step_seconds,
+            "variables": variables}
 
 
 def train_cluster(cluster: str, *, steps: int = 50, batch: int = 64,
@@ -459,7 +423,7 @@ def main(argv=None) -> int:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
-    add_engine_options(ap)
+    add_fusion_options(ap)
     add_cluster_options(ap, replication=True, standby=True)
     add_obs_options(ap, summary=True)
     args = ap.parse_args(argv)
@@ -480,8 +444,7 @@ def main(argv=None) -> int:
         res = train(args.arch, smoke=args.smoke, steps=args.steps,
                     batch=args.batch, seq=args.seq, lr=args.lr,
                     ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
-                    engine=args.engine, numerics=args.numerics,
-                    backend=args.backend, summary_dir=args.summary_dir,
+                    summary_dir=args.summary_dir,
                     metrics_every=args.metrics_every)
     print(f"[train] done: final loss {res['final_loss']:.4f}")
     return 0

@@ -176,7 +176,7 @@ def test_trace_off_is_zero_overhead():
     """Tracing disabled = no recorder anywhere: no global slot, no
     session recorder, and a run records nothing (the disabled path is a
     single ``is None`` check, asserted structurally rather than with a
-    flaky wall-clock bound — benchmarks/run.py b15 measures the time)."""
+    flaky wall-clock bound)."""
     spans_mod.install(None)
     b, s, x = _mm_graph()
     sess = Session(b.graph)

@@ -17,7 +17,7 @@ import repro.core.session as session_mod
 from repro.core import GraphBuilder, Session
 from repro.core.executable import RunSignature
 from repro.core.options import SessionOptions, parse_guard
-from repro.launch.cli import (add_cluster_options, add_engine_options,
+from repro.launch.cli import (add_cluster_options, add_fusion_options,
                               session_options_from_args)
 
 
@@ -126,7 +126,7 @@ def test_run_signature_tracks_option_fields():
 
 def _parser(**kw):
     ap = argparse.ArgumentParser()
-    add_engine_options(ap)
+    add_fusion_options(ap)
     add_cluster_options(ap, **kw)
     return ap
 
